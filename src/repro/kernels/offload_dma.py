@@ -29,7 +29,6 @@ _SLOTS = 2
 
 def _dma_copy_kernel(src_ref, dst_ref):
     n = src_ref.shape[0]                                # chunks
-    chunk = src_ref.shape[1]
 
     def body(scratch, in_sems, out_sems):
         def copy_in(i, slot):
@@ -61,7 +60,7 @@ def _dma_copy_kernel(src_ref, dst_ref):
         jax.lax.fori_loop(0, n, step, 0)
 
     pl.run_scoped(body,
-                  pltpu.VMEM((_SLOTS, chunk), src_ref.dtype),
+                  pltpu.VMEM((_SLOTS,) + src_ref.shape[1:], src_ref.dtype),
                   pltpu.SemaphoreType.DMA((_SLOTS,)),
                   pltpu.SemaphoreType.DMA((_SLOTS,)))
 
@@ -69,7 +68,7 @@ def _dma_copy_kernel(src_ref, dst_ref):
 def dma_copy(x, *, chunk_elems: int = 1 << 15, interpret: bool = False):
     """Copy ``x`` through the double-buffered DMA pipeline.
 
-    Flattens to ``(n_chunks, chunk_elems)`` (zero-padded tail), runs the
+    Flattens to chunks of ``chunk_elems`` (zero-padded tail), runs the
     kernel, and restores the original shape.  Returns an array equal to
     ``x``; on TPU the copy is a pipelined pair of DMA streams instead of
     one blocking transfer.
@@ -80,11 +79,15 @@ def dma_copy(x, *, chunk_elems: int = 1 << 15, interpret: bool = False):
     pad = (-n) % chunk
     if pad:
         flat = jnp.pad(flat, (0, pad))
-    chunks = flat.reshape(-1, chunk)
+    # each chunk a (rows, 128) tile stack, so that a chunk is an index on
+    # an untiled leading dim (Mosaic slices tiled dims only at multiples
+    # of 8 rows)
+    lanes = 128 if chunk % 128 == 0 else chunk
+    chunks = flat.reshape(-1, chunk // lanes, lanes)
     out = pl.pallas_call(
         _dma_copy_kernel,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct(chunks.shape, chunks.dtype),
         interpret=interpret,
     )(chunks)

@@ -30,13 +30,22 @@ def flash_attention(q, k, v, kv_len=None, *, causal: bool = True,
     ``kv_len``: optional (B,) int32 true lengths of a bucket-padded batch
     — padded keys are masked and fully-padded blocks skipped, so the
     kernel does work proportional to the *effective* tokens while the
-    compiled shape stays the bucket shape.
+    compiled shape stays the bucket shape.  A sequence longer than one
+    128-block is padded to a block multiple (the pad is masked as
+    padding and sliced off).
     """
+    B, S = q.shape[:2]
+    pad = (-S) % 128 if S > 128 else 0
+    if pad:
+        q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for t in (q, k, v))
+        if kv_len is None:
+            kv_len = jnp.full((B,), S, jnp.int32)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     o = _fa.flash_attention(qt, kt, vt, kv_len, causal, window, not _on_tpu())
-    return o.transpose(0, 2, 1, 3)
+    return o.transpose(0, 2, 1, 3)[:, :S]
 
 
 @partial(jax.jit, static_argnames=("chunk_elems",))

@@ -1,6 +1,7 @@
 """Roofline analysis from compiled dry-run artifacts (no hardware needed).
 
-Three terms per (arch × shape × mesh), TPU v5e constants:
+Three terms per (arch × shape × mesh), at the peaks of the chip's row in
+``PEAKS`` (TPU v5e):
 
     compute    = HLO_FLOPs_per_device / peak_FLOPs_per_chip
     memory     = HLO_bytes_per_device / HBM_bandwidth_per_chip
@@ -19,12 +20,50 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
+import jax
 import numpy as np
 
-# ---- TPU v5e constants (per task spec) ------------------------------------
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator kind."""
+    flops: float             # bf16 FLOP/s per chip
+    hbm_bw: float            # bytes/s per chip
+    ici_bw: float            # bytes/s per chip-to-chip link
+    source: str
+
+
+# keyed by ``jax.Device.device_kind``
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI over 4 links"),
+}
+# the row plans are priced against where no TPU is attached (CPU tests,
+# dry runs): the chip this repository targets
+PLANNING_KIND = "TPU v5 lite"
+
+
+def device_peaks(device=None) -> ChipPeaks:
+    """Peaks of ``device`` (default: the first visible device).  A TPU
+    whose kind has no row in ``PEAKS`` is an error, never the v5e row;
+    a host without a TPU plans against the v5e row by name."""
+    device = device if device is not None else jax.devices()[0]
+    if device.platform != "tpu":
+        return PEAKS[PLANNING_KIND]
+    try:
+        return PEAKS[device.device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for TPU kind "
+                       f"{device.device_kind!r}; add its row to "
+                       f"repro.launch.roofline.PEAKS") from None
+
+
+# the planner's pricing constants: the planning chip's row
+PEAK_FLOPS = PEAKS[PLANNING_KIND].flops
+HBM_BW = PEAKS[PLANNING_KIND].hbm_bw
+ICI_BW = PEAKS[PLANNING_KIND].ici_bw
 # effective host<->device link for activation offload (PCIe 4.0 x16 is
 # ~32 GB/s raw; 16 GB/s is the sustained-DMA default the --pcie-gbps
 # knob overrides).  The hybrid scheduler prices OFFLOAD actions with it.

@@ -17,6 +17,10 @@ paper's §4.3 estimator re-aimed at cache bytes) predicts the footprint
 of each admit and each prefill chunk before allocating, so an
 over-subscribed trace *defers* instead of OOMing; a request that can
 never fit is rejected with a reason, never a crash.
+
+``main`` returns the run's summary (``ServeResult.summary()`` plus each
+request's generated tokens), so a caller in the same process reads
+results instead of parsing stdout.
 """
 from __future__ import annotations
 
@@ -28,6 +32,7 @@ import jax
 
 from repro.data.pipeline import DISTRIBUTIONS
 from repro.data.trace import TraceRequest, gen_trace
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.report import serve_report
 from repro.models.lm import build_model
 from repro.obs import build_telemetry, flush_telemetry
@@ -35,7 +40,7 @@ from repro.models.registry import get_config
 from repro.train.engine import ServeEngine
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
     ap.add_argument("--dataset", default="swag", choices=list(DISTRIBUTIONS))
@@ -72,7 +77,8 @@ def main():
     ap.add_argument("--trace-out", default=None,
                     help="Chrome trace_event JSON (Perfetto): per-request "
                          "queue-wait, prefill-chunk and decode-batch spans")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -111,12 +117,14 @@ def main():
     result = engine.run(trace)
     print(f"served in {time.time() - t0:.2f}s\n")
     print(serve_report(engine, result))
+    summary = result.summary()
     if args.save:
         with open(args.save, "w") as f:
-            json.dump(result.summary(), f, indent=2)
+            json.dump(summary, f, indent=2)
         print(f"\nsummary written to {args.save}")
     for kind, path in flush_telemetry(telemetry).items():
         print(f"{kind} written to {path}")
+    return dict(summary, outputs=result.outputs)
 
 
 if __name__ == "__main__":

@@ -7,15 +7,20 @@ CPU-runnable example (reduced scale):
 Sharding-aware planning: ``--mesh-shape 4x2 --hbm-gb 16`` plans against
 the *per-device* budget of a (data=4, model=2) mesh — activations and
 fixed bytes divided by their PartitionSpec divisors, ZeRO-1 aware with
-``--zero1``.  When enough devices are visible the step compiles under
-the Mesh context (inputs stay replicated — this driver passes no
-explicit shardings); end-to-end *sharded* execution is validated by the
-dry-run path (launch/dryrun.py), which lowers the step with full
-param/batch/optimizer NamedShardings.
+``--zero1``.  The step compiles under the Mesh context (inputs stay
+replicated — this driver passes no explicit shardings); a mesh with
+more devices than are visible is an error.  End-to-end *sharded*
+execution is validated by the dry-run path (launch/dryrun.py), which
+lowers the step with full param/batch/optimizer NamedShardings.
+
+``main`` returns the run's summary (``Trainer.summary()`` plus the
+per-step history), so a caller in the same process reads results
+instead of parsing stdout.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import itertools
 import time
 
@@ -25,8 +30,10 @@ import numpy as np
 
 from repro.core import (DTRSimPlanner, MeshBudget, MimosePlanner,
                         NonePlanner, SublinearPlanner)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh, parse_mesh_shape
 from repro.launch.report import engine_report
+from repro.launch.roofline import device_peaks
 from repro.obs import build_telemetry, flush_telemetry
 from repro.data.pipeline import (DISTRIBUTIONS, bucket_length, make_batches,
                                  top_buckets)
@@ -143,6 +150,8 @@ def main(argv=None):
                          "chrome://tracing): per-step plan/compile/execute "
                          "spans, planner and transfer tracks")
     args = ap.parse_args(argv)
+    enable_compile_cache()
+    peaks = device_peaks()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -153,7 +162,9 @@ def main(argv=None):
     n_params = sum(int(np.prod(l.shape))
                    for l in jax.tree_util.tree_leaves(params))
     print(f"arch={cfg.name} params={n_params / 1e6:.1f}M "
-          f"units={lm.num_plan_units()}")
+          f"units={lm.num_plan_units()} "
+          f"device={jax.devices()[0].device_kind} "
+          f"peak={peaks.flops / 1e12:.0f}TFLOP/s")
 
     budget = args.budget_mb * 2**20 if args.budget_mb else 1e18
     mesh_budget = mesh = None
@@ -163,21 +174,15 @@ def main(argv=None):
                                             zero1=args.zero1)
         # explicit --budget-mb overrides the per-device HBM
         budget = args.budget_mb * 2**20 if args.budget_mb else None
-        n_dev = int(np.prod(shape))
-        if len(jax.devices()) >= n_dev:
-            # the Mesh context lets XLA honour any sharding constraints
-            # the model emits; this driver does not device_put explicit
-            # param/batch shardings, so data stays replicated — fully
-            # sharded execution is the dry-run's job (launch/dryrun.py)
-            mesh = make_production_mesh(shape=shape)
-            print(f"mesh {shape}: planning per-device; compiling under "
-                  f"the {n_dev}-device mesh context (inputs replicated — "
-                  "see launch/dryrun.py for sharded execution)")
-        else:
-            print(f"mesh {shape}: {n_dev} devices unavailable "
-                  f"({len(jax.devices())} visible) — planning per-device, "
-                  "executing single-device (see launch/dryrun.py for "
-                  "sharded execution)")
+        # the Mesh context lets XLA honour any sharding constraints the
+        # model emits; this driver does not device_put explicit
+        # param/batch shardings, so data stays replicated — fully
+        # sharded execution is the dry-run's job (launch/dryrun.py).
+        # Too few visible devices raise here.
+        mesh = make_production_mesh(shape=shape)
+        print(f"mesh {shape}: planning per-device; compiling under the "
+              f"{mesh.devices.size}-device mesh context (inputs "
+              "replicated — see launch/dryrun.py for sharded execution)")
     dist = DISTRIBUTIONS[args.dataset]
     max_size = args.batch_size * bucket_length(dist.hi, args.quantum)
     if args.offload and args.byte_only_remat:
@@ -298,7 +303,8 @@ def main(argv=None):
                                data_cursor=trainer.data_cursor)
         print("snapshot", final)
     print(f"done in {time.time() - t0:.1f}s")
-    print("summary:", trainer.summary())
+    summary = trainer.summary()
+    print("summary:", summary)
     print("\nengine report (where the padding went):")
     print(engine_report(trainer, planner))
     if hasattr(planner, "stats"):
@@ -309,6 +315,8 @@ def main(argv=None):
         print("saved", args.save)
     for kind, path in flush_telemetry(telemetry).items():
         print(f"{kind} written to {path}")
+    return dict(summary,
+                history=[dataclasses.asdict(s) for s in trainer.history])
 
 
 if __name__ == "__main__":

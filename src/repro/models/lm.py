@@ -46,32 +46,22 @@ OFFLOAD_RESIDUAL_NAME = "mimose_offload_resid"
 
 def host_offload_policy():
     """``jax.checkpoint`` policy offloading the named residual-stream
-    checkpoint to pinned host memory.  Returns ``None`` (plain
-    save-nothing remat) on jaxlib builds without offload support, so an
-    OFFLOAD plan still executes correctly everywhere."""
-    try:
-        return jax.checkpoint_policies.save_and_offload_only_these_names(
-            names_which_can_be_saved=[],
-            names_which_can_be_offloaded=[OFFLOAD_RESIDUAL_NAME],
-            offload_src="device", offload_dst="pinned_host")
-    except (AttributeError, TypeError):
-        return None
+    checkpoint to pinned host memory."""
+    return jax.checkpoint_policies.save_and_offload_only_these_names(
+        names_which_can_be_saved=[],
+        names_which_can_be_offloaded=[OFFLOAD_RESIDUAL_NAME],
+        offload_src="device", offload_dst="pinned_host")
 
 
 def _offload_unit(fn):
     """Wrap a pure ``fn(params, x, ...)`` unit so its input checkpoint is
     tagged for host offload, then checkpoint it under the offload
-    policy.  Under an outer jit (the trainer's step) the checkpoint is
-    used as-is; in eager execution it is additionally jit-wrapped,
-    because the host transfer (``TransferToMemoryKind``) is only legal
-    under jit — eager OFFLOAD replays therefore pay a per-call trace,
-    which is fine for the tests/debugging that path serves."""
+    policy.  The checkpoint is jit-wrapped because the host transfer
+    (``TransferToMemoryKind``) is only legal under jit; under an outer
+    jit (the trainer's step) the nested jit is inlined."""
     def tagged(p, x, *rest):
         return fn(p, checkpoint_name(x, OFFLOAD_RESIDUAL_NAME), *rest)
-    ckpt = jax.checkpoint(tagged, policy=host_offload_policy())
-    if jax.core.trace_state_clean():
-        return jax.jit(ckpt)
-    return ckpt
+    return jax.jit(jax.checkpoint(tagged, policy=host_offload_policy()))
 
 
 # ---------------------------------------------------------------------------
@@ -99,11 +89,9 @@ def _mesh_probe_sig(mesh) -> tuple:
 
 def spmd_offload_supported(mesh=None) -> bool:
     """True when OFFLOAD actions can execute as real host offload under
-    ``mesh``.  Single device (or no mesh): just needs the offload
-    policy.  SPMD: try-compiling a tiny offloaded grad under the mesh
-    answers for this exact (jaxlib, backend, mesh-shape) combination."""
-    if host_offload_policy() is None:
-        return False
+    ``mesh``.  Single device (or no mesh): always.  SPMD: try-compiling
+    a tiny offloaded grad under the mesh answers for this exact
+    (jaxlib, backend, mesh-shape) combination."""
     if mesh is None or int(mesh.devices.size) <= 1:
         return True
     sig = _mesh_probe_sig(mesh)

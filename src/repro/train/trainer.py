@@ -82,6 +82,9 @@ class StepStats:
     # holds to a tolerance band
     exposed_transfer_s: float = 0.0
     sim_transfer_s: float = 0.0
+    # the planner's predicted per-device peak under this step's plan:
+    # fixed bytes plus the activation bytes the plan keeps on the device
+    planned_peak_bytes: float = 0.0
 
 
 class Trainer:
@@ -557,6 +560,11 @@ class Trainer:
             st = getattr(self.planner, "stats", None)
             if isinstance(st, MutableMapping):
                 st["offload_fallbacks"] = st.get("offload_fallbacks", 0) + 1
+        fixed = float(self.planner.fixed_bytes or 0.0)
+        planned_peak = fixed + (
+            float(info.plan.est_activation_bytes)
+            - float(info.plan.covered_bytes)
+        ) / self.planner.activation_divisor_scalar()
         self.history.append(StepStats(loss, t_step, t_plan, is_new,
                                       info.plan.n_remat, eff_tokens, bucket,
                                       padded_tokens,
@@ -566,7 +574,8 @@ class Trainer:
                                           info.plan, "n_opt", 0),
                                       offload_degraded=degraded,
                                       exposed_transfer_s=exposed_s,
-                                      sim_transfer_s=sim_s))
+                                      sim_transfer_s=sim_s,
+                                      planned_peak_bytes=planned_peak))
         if tel.events_on:
             tel.events.emit("train_step", step=self.global_step,
                             bucket=bucket, loss=loss, k=k,
@@ -577,9 +586,8 @@ class Trainer:
                             n_offload=int(info.plan.n_offload),
                             step_time_s=t_step, plan_time_s=t_plan,
                             exposed_transfer_s=exposed_s,
-                            predicted_peak_bytes=float(
-                                self.planner.fixed_bytes or 0.0)
-                            + float(info.plan.est_activation_bytes))
+                            predicted_peak_bytes=fixed + float(
+                                info.plan.est_activation_bytes))
         self.global_step += 1
         self.data_cursor += 1
         if self.snapshots is not None and self.snapshots.due(self.global_step):

@@ -87,7 +87,7 @@ def to_host(x):
     """Move ``x`` to host memory: pinned (async-DMA-capable) when the
     build supports it, pageable numpy otherwise."""
     if host_memory_supported():
-        return jax.device_put(x, _host_sharding(x), donate=True)
+        return jax.device_put(x, _host_sharding(x))
     return jax.device_get(x)
 
 

@@ -52,6 +52,8 @@ GRAD_CASES = [
     (2, 96, 4, 2, 16, True, 0),       # GQA group reduce in dk/dv
     (1, 128, 2, 2, 32, True, 32),     # sliding window backward
     (1, 64, 4, 1, 16, False, 0),      # bidirectional, extreme GQA
+    (1, 512, 4, 2, 64, True, 0),      # S > block_k: blocked dk/dv
+    (1, 256, 2, 2, 32, True, 64),     # windowed, several k blocks
 ]
 
 

@@ -29,7 +29,7 @@ from repro.kernels.ops import residual_dma_copy
 from repro.launch.report import engine_report
 from repro.models import lm as lm_mod
 from repro.models.lm import (build_model, configure_offload,
-                             host_offload_policy, spmd_offload_supported)
+                             spmd_offload_supported)
 from repro.models.registry import get_config
 from repro.train.resilience import planner_state, restore_planner_state
 from repro.train.transfer import (CALIBRATION_ENV, PCIE_ENV, TransferLane,
@@ -206,17 +206,12 @@ def test_opt_bytes_planning_gated_off_in_scan_mode(tiny, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# host_offload_policy fallback + SPMD probe / degradation surfacing
+# SPMD probe / degradation surfacing
 # ---------------------------------------------------------------------------
 
-def test_host_offload_policy_none_fallback(monkeypatch):
-    monkeypatch.delattr(jax, "checkpoint_policies")
-    assert host_offload_policy() is None
-    assert spmd_offload_supported() is False
-
-
 def test_configure_offload_degrades_and_warns_once(monkeypatch):
-    monkeypatch.delattr(jax, "checkpoint_policies")
+    monkeypatch.setattr(lm_mod, "spmd_offload_supported",
+                        lambda mesh=None: False)
     monkeypatch.setattr(lm_mod, "_spmd_offload_warned", set())
     stub = types.SimpleNamespace(offload_exec=True)
     with pytest.warns(RuntimeWarning, match="host offload unavailable"):
@@ -229,8 +224,6 @@ def test_configure_offload_degrades_and_warns_once(monkeypatch):
 
 
 def test_configure_offload_keeps_capable_runtimes_enabled():
-    if host_offload_policy() is None:
-        pytest.skip("jaxlib build has no offload policy")
     assert spmd_offload_supported() is True       # single device
     stub = types.SimpleNamespace(offload_exec=False)
     assert configure_offload(stub) is False
