@@ -32,9 +32,11 @@ ROOT = pathlib.Path(__file__).resolve().parent
 SRC = ROOT / "src"
 
 # the paper's training job at full width: 12 x d=768, vocab 30522
-TRAIN_ARGS = ["--arch", "bert_base_paper", "--dataset", "squad",
-              "--planner", "mimose", "--quantum", "128",
-              "--batch-size", "8"]
+TRAIN_ARCH, TRAIN_DATASET, TRAIN_QUANTUM, TRAIN_BATCH = \
+    "bert_base_paper", "squad", 128, 8
+TRAIN_ARGS = ["--arch", TRAIN_ARCH, "--dataset", TRAIN_DATASET,
+              "--planner", "mimose", "--quantum", str(TRAIN_QUANTUM),
+              "--batch-size", str(TRAIN_BATCH)]
 TRAIN_STEPS = 8
 MESH_STEPS = 4
 # the budget sits this far below the planner's own no-remat peak of the
@@ -218,10 +220,6 @@ def _cpu_step0_loss(arch: str, dataset: str, batch_size: int,
         return float(loss)
 
 
-def _arg(args, name):
-    return args[args.index(name) + 1]
-
-
 def phase_train() -> None:
     import jax
     import numpy as np
@@ -229,9 +227,8 @@ def phase_train() -> None:
     from repro.train.transfer import measure_pcie_gbps
 
     t0 = phase("train")
-    arch, dataset = _arg(TRAIN_ARGS, "--arch"), _arg(TRAIN_ARGS, "--dataset")
-    quantum = int(_arg(TRAIN_ARGS, "--quantum"))
-    batch_size = int(_arg(TRAIN_ARGS, "--batch-size"))
+    arch, dataset = TRAIN_ARCH, TRAIN_DATASET
+    quantum, batch_size = TRAIN_QUANTUM, TRAIN_BATCH
     from repro.data.pipeline import DISTRIBUTIONS, bucket_length
     top = bucket_length(DISTRIBUTIONS[dataset].hi, quantum)
 
@@ -298,7 +295,7 @@ def phase_offload() -> None:
 
     t0 = phase("offload")
     check(host_memory_supported(), "pinned_host memory supported")
-    lm = build_model(get_config(_arg(TRAIN_ARGS, "--arch")))
+    lm = build_model(get_config(TRAIN_ARCH))
     check(configure_offload(lm, None) is False and lm.offload_exec,
           "configure_offload: OFFLOAD executes as host offload on one chip")
 
@@ -320,7 +317,7 @@ def phase_offload() -> None:
     check(np.array_equal(np.asarray(residual_dma_copy(x)), np.asarray(x)),
           f"DMA copy kernel: {x.nbytes >> 20} MiB copied bitwise equal")
 
-    B, S = int(_arg(TRAIN_ARGS, "--batch-size")), 512
+    B, S = TRAIN_BATCH, 512
     ones = np.ones((B, S), np.int32)
     params = lm.init(jax.random.PRNGKey(0))
     unit = lm.plan_units(params, {"tokens": ones, "labels": ones})[0]

@@ -7,8 +7,7 @@ that makes the overlap real instead of aspirational:
 * ``to_host`` moves an array to pinned host memory via
   ``jax.device_put`` with a ``pinned_host`` memory-kind sharding when
   the jaxlib build supports it, degrading to ``jax.device_get``
-  (pageable numpy) otherwise — the same capability split as
-  ``repro.models.lm.host_offload_policy``.
+  (pageable numpy) otherwise.
 * ``TransferLane`` runs those copies on ONE dedicated worker thread
   with a bounded in-flight depth of two (classic double buffering: one
   copy draining while the next is queued).  Only time a caller spends
@@ -376,7 +375,7 @@ def measure_pcie_gbps(size_mb: int = 64, repeats: int = 3) -> dict:
     best_in = 0.0
     for _ in range(max(int(repeats), 1)):
         t0 = time.perf_counter()
-        h = to_host(jax.device_put(np.ones((n,), np.float32)))
+        h = to_host(x)
         jax.block_until_ready(h)
         dt = time.perf_counter() - t0
         best_out = max(best_out, nbytes / dt / 1e9)
