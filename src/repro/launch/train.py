@@ -16,6 +16,11 @@ lowers the step with full param/batch/optimizer NamedShardings.
 ``main`` returns the run's summary (``Trainer.summary()`` plus the
 per-step history), so a caller in the same process reads results
 instead of parsing stdout.
+
+``--profile-dir DIR`` captures a ``jax.profiler`` trace of the steps
+after prewarm, with the trainer's spans in it as ``program:<span>``
+annotations (``repro.obs.SpanTracer(to_profiler=True)``), so the
+device's idle gaps are named by what the host was doing.
 """
 from __future__ import annotations
 
@@ -149,6 +154,11 @@ def main(argv=None):
                     help="Chrome trace_event JSON (load in Perfetto / "
                          "chrome://tracing): per-step plan/compile/execute "
                          "spans, planner and transfer tracks")
+    ap.add_argument("--profile-dir", default=None,
+                    help="capture a jax.profiler trace of the steps after "
+                         "prewarm into this directory; the trainer's spans "
+                         "appear in it as program:<span> annotations, so "
+                         "the device's idle time is named by them")
     args = ap.parse_args(argv)
     enable_compile_cache()
     peaks = device_peaks()
@@ -248,7 +258,8 @@ def main(argv=None):
                            injector=injector)
     telemetry = build_telemetry(metrics_path=args.metrics,
                                 events_path=args.events_out,
-                                trace_path=args.trace_out)
+                                trace_path=args.trace_out,
+                                to_profiler=bool(args.profile_dir))
     trainer = Trainer(lm, planner, opt, mesh=mesh,
                       watchdog=watchdog, snapshots=snapshots,
                       telemetry=telemetry)
@@ -285,6 +296,13 @@ def main(argv=None):
                             args.batch_size)
         print(f"prewarmed {n} bucket(s) {[S for S, _ in likely]} "
               f"in {time.time() - tw:.1f}s")
+    if args.profile_dir:
+        # host annotations without the Python call tracer, which would
+        # slow the host and so widen the very gaps the trace shows
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(args.profile_dir, profiler_options=opts)
     for i, batch in enumerate(batches):
         params, opt_state, loss = trainer.step(params, opt_state, batch)
         if i % 10 == 0 or i == args.steps - 1:
@@ -292,6 +310,9 @@ def main(argv=None):
             print(f"step {i:4d} loss {loss:.4f} S={batch['tokens'].shape[1]}"
                   f" remat={st.remat_units} offload={st.offload_units}"
                   f" k={st.microbatches} step_s={st.step_time_s:.3f}")
+    if args.profile_dir:
+        jax.profiler.stop_trace()
+        print(f"profile written to {args.profile_dir}")
     bs = getattr(planner, "background_solver", None)
     if bs is not None:
         # let in-flight solves land so the final snapshot and report see

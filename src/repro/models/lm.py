@@ -237,13 +237,15 @@ def block_apply(params: dict, cfg: ModelConfig, x: Array, kind: str, *,
         return x, (new_cache or None), aux
 
     # attention-bearing blocks -------------------------------------------
-    h, new_kv = L.attention_apply(
-        params["attn"], cfg, L.rmsnorm_apply(params["norm1"], x, eps),
-        positions=positions, layer_is_global=layer_is_global,
-        kv_cache=None if cache is None else {"k": cache["k"], "v": cache["v"]},
-        cache_index=cache_index, impl=impl,
-        mrope_positions=mrope_positions,
-        causal=(kind != "enc"), kv_len=seq_lens)
+    with jax.named_scope("attn"):
+        h, new_kv = L.attention_apply(
+            params["attn"], cfg, L.rmsnorm_apply(params["norm1"], x, eps),
+            positions=positions, layer_is_global=layer_is_global,
+            kv_cache=None if cache is None
+            else {"k": cache["k"], "v": cache["v"]},
+            cache_index=cache_index, impl=impl,
+            mrope_positions=mrope_positions,
+            causal=(kind != "enc"), kv_len=seq_lens)
     x = x + h
     if new_kv is not None:
         new_cache.update(k=new_kv["k"], v=new_kv["v"])
@@ -270,7 +272,8 @@ def block_apply(params: dict, cfg: ModelConfig, x: Array, kind: str, *,
         mo, aux = MOE.moe_apply(params["moe"], cfg, h2)
         x = x + mo
     else:
-        x = x + L.mlp_apply(params["mlp"], h2, cfg.mlp_act)
+        with jax.named_scope("mlp"):
+            x = x + L.mlp_apply(params["mlp"], h2, cfg.mlp_act)
     return x, (new_cache or None), aux
 
 
@@ -473,17 +476,22 @@ class LM:
                 elif dec_actions[i] is Action.OFFLOAD:
                     one = (_offload_unit(one) if self.offload_exec
                            else jax.checkpoint(one, policy=remat_policy))
-                x, a = one(bp, x)
+                # the scope names the unit's ops in a profile: forward,
+                # backward (transpose) and recompute (rematted_computation)
+                with jax.named_scope(f"unit{i}"):
+                    x, a = one(bp, x)
                 x = self._constrain(x)
                 aux = aux + a
 
         if self.last_logits_only:
             x = x[:, -1:]
-        x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-        head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-        logits = x @ head
-        if self.logits_f32:
-            logits = logits.astype(jnp.float32)
+        with jax.named_scope("head"):
+            x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
+            head = (params["embed"].T if cfg.tie_embeddings
+                    else params["lm_head"])
+            logits = x @ head
+            if self.logits_f32:
+                logits = logits.astype(jnp.float32)
         return logits, aux
 
     def _forward_scan(self, params, x, positions, chunk_actions, enc_out,
@@ -607,11 +615,13 @@ class LM:
         # model-sharded, so avoid take_along_axis (which would all-gather
         # the full logits).  one_hot contracts the vocab axis locally and
         # reduces across the model axis instead.
-        lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
-        onehot = jax.nn.one_hot(labels, logits.shape[-1], dtype=logits.dtype)
-        label_logit = jnp.einsum("bsv,bsv->bs", logits, onehot,
-                                 preferred_element_type=jnp.float32)
-        nll = lse - label_logit
+        with jax.named_scope("head"):
+            lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+            onehot = jax.nn.one_hot(labels, logits.shape[-1],
+                                    dtype=logits.dtype)
+            label_logit = jnp.einsum("bsv,bsv->bs", logits, onehot,
+                                     preferred_element_type=jnp.float32)
+            nll = lse - label_logit
         total_w = jnp.maximum(jnp.sum(weights), 1.0)
         ce = jnp.sum(nll * weights) / total_w
         loss = ce + aux

@@ -78,13 +78,17 @@ class Telemetry:
 
 def build_telemetry(metrics_path: Optional[str] = None,
                     events_path: Optional[str] = None,
-                    trace_path: Optional[str] = None) -> Telemetry:
+                    trace_path: Optional[str] = None,
+                    to_profiler: bool = False) -> Telemetry:
     """Construct Telemetry from launch-driver flags.
 
     Any non-None path turns its surface on; ``flush_telemetry`` writes
-    the artifacts at exit.  All three None → fully disabled."""
+    the artifacts at exit.  ``to_profiler`` turns the span tracer on and
+    forwards its spans to ``jax.profiler`` (``SpanTracer``).  All None
+    and False → fully disabled."""
     events = EventLog(path=events_path) if events_path else None
-    tracer = SpanTracer() if trace_path else None
+    tracer = (SpanTracer(to_profiler=to_profiler)
+              if trace_path or to_profiler else None)
     tel = Telemetry(events=events, tracer=tracer)
     tel._paths = {"metrics": metrics_path, "events": events_path,  # type: ignore[attr-defined]
                   "trace": trace_path}

@@ -7,6 +7,13 @@ own row in the Perfetto UI, so the transfer lane's measured
 ``exposed`` spans sit visually under the ``execute`` span they steal
 time from.
 
+Spans are timed on ``time.perf_counter``, which a ``jax.profiler``
+trace cannot be lined up with.  ``SpanTracer(to_profiler=True)`` also
+opens every span as a ``jax.profiler.TraceAnnotation`` named
+``program:<span>``, and a step span (:meth:`SpanTracer.step_span`) as a
+``jax.profiler.StepTraceAnnotation`` besides, so a profiler trace taken
+around the run names the device's idle gaps by the program's spans.
+
 The disabled path is a strict no-op: :class:`NullTracer.span` returns
 one shared :data:`NULL_SPAN` singleton (no allocation per call) whose
 ``__enter__``/``__exit__`` do nothing.
@@ -63,6 +70,29 @@ class _Span:
         return False
 
 
+class _ProfiledSpan(_Span):
+    """A :class:`_Span` that also holds profiler annotations open while
+    it is open (outermost first)."""
+
+    __slots__ = ("_annotations",)
+
+    def __init__(self, tracer: "SpanTracer", name: str, track: int,
+                 args: Optional[dict], annotations: tuple):
+        super().__init__(tracer, name, track, args)
+        self._annotations = annotations
+
+    def __enter__(self):
+        for a in self._annotations:
+            a.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        for a in reversed(self._annotations):
+            a.__exit__(*exc)
+        return False
+
+
 class _NullSpan:
     """Shared do-nothing span for the disabled path."""
 
@@ -86,11 +116,19 @@ class SpanTracer:
     (serve queue-wait, virtual-clock engines) land on the same tracks.
     Appends to the event list are GIL-atomic, so the transfer-lane
     worker thread and the train thread can trace concurrently.
+
+    ``to_profiler``: also open every span as a ``jax.profiler``
+    annotation named ``program:<span>`` (module docstring).  Off by
+    default, so a subclass that forwards spans itself is not doubled.
     """
 
     enabled = True
 
-    def __init__(self, capacity: int = 200_000):
+    def __init__(self, capacity: int = 200_000, to_profiler: bool = False):
+        self.to_profiler = bool(to_profiler)
+        if self.to_profiler:
+            from jax import profiler
+            self._profiler = profiler
         self._events: List[dict] = []
         self._capacity = int(capacity)
         self._pid = os.getpid()
@@ -99,7 +137,22 @@ class SpanTracer:
 
     def span(self, name: str, track: int = TRACK_STEP,
              args: Optional[dict] = None) -> _Span:
+        if self.to_profiler:
+            return _ProfiledSpan(self, name, track, args, (
+                self._profiler.TraceAnnotation(f"program:{name}"),))
         return _Span(self, name, track, args)
+
+    def step_span(self, name: str, step_num: int) -> _Span:
+        """``span(name)`` for one whole training step.  With
+        ``to_profiler`` on it sits inside a
+        ``jax.profiler.StepTraceAnnotation("train", step_num=...)``, so
+        the profiler groups the device's work by step."""
+        if self.to_profiler:
+            return _ProfiledSpan(self, name, TRACK_STEP, None, (
+                self._profiler.StepTraceAnnotation("train",
+                                                   step_num=step_num),
+                self._profiler.TraceAnnotation(f"program:{name}")))
+        return self.span(name, TRACK_STEP)
 
     def complete(self, name: str, start_s: float, dur_s: float,
                  track: int = TRACK_STEP,
@@ -162,6 +215,9 @@ class NullTracer:
 
     def span(self, name: str, track: int = TRACK_STEP,
              args: Optional[dict] = None) -> _NullSpan:
+        return NULL_SPAN
+
+    def step_span(self, name: str, step_num: int) -> _NullSpan:
         return NULL_SPAN
 
     def complete(self, name, start_s, dur_s, track=TRACK_STEP,

@@ -151,7 +151,8 @@ def accumulated_step_fn(lm, optimizer, actions, k: int, remat_policy=None):
     def train_step(params, opt_state, batch):
         loss, metrics, grads = accumulated_grads(
             lm, params, batch, k, actions=actions, remat_policy=remat_policy)
-        new_params, new_opt = optimizer.update(grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(grads, opt_state, params)
         return new_params, new_opt, loss, metrics
 
     return train_step

@@ -272,7 +272,8 @@ class Trainer:
                 return loss, metrics
             (loss, metrics), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
-            new_params, new_opt = opt.update(grads, opt_state, params)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = opt.update(grads, opt_state, params)
             return new_params, new_opt, loss, metrics
 
         return jax.jit(train_step, donate_argnums=(0, 1))
@@ -294,11 +295,15 @@ class Trainer:
         """Mesh context for compile + execute (no-op without a mesh)."""
         return self.mesh if self.mesh is not None else contextlib.nullcontext()
 
-    def _get_step_fn(self, mask, batch, microbatch: int = 1):
+    def _get_step_fn(self, mask, batch, microbatch: int = 1, bucket=None):
         key = self._step_key(mask, batch, microbatch)
         fn = self._step_cache.get(key)
         if fn is None:
-            fn = self._build_step(mask, microbatch)
+            tel = self.telemetry
+            with tel.tracer.span("build_step", TRACK_STEP,
+                                 args={"bucket": bucket}
+                                 if tel.trace_on else None):
+                fn = self._build_step(mask, microbatch)
             self._step_cache[key] = fn
             self.cache_stats["compiles"] += 1
             self.cache_stats["evictions"] = self._step_cache.evictions
@@ -452,9 +457,29 @@ class Trainer:
 
     # ------------------------------------------------------------------
     def step(self, params, opt_state: AdamWState, batch) -> tuple:
+        """One training step.  Every host stretch of it is a span on
+        ``TRACK_STEP`` inside the outer ``step``: ``prepare``, ``plan``,
+        ``build_step`` (a step-cache miss), ``execute`` (its ``dispatch``
+        and the ``sync`` on the loss) and ``record`` (the bookkeeping
+        after the step), so a profiler trace names the device time the
+        host leaves idle."""
+        tracer = self.telemetry.tracer
+        with tracer.step_span("step", self.global_step):
+            with tracer.span("prepare", TRACK_STEP):
+                batch = self._prepare(batch)
+            params, opt_state, loss, metrics, ctx = self._execute(
+                params, opt_state, batch)
+            with tracer.span("record", TRACK_STEP):
+                self._record(params, opt_state, loss, metrics, batch, ctx)
+        return params, opt_state, loss
+
+    def _execute(self, params, opt_state: AdamWState, batch) -> tuple:
+        """Plan, build or look up the step, run it and wait for its loss,
+        under the OOM watchdog's retry loop.  ``ctx`` carries what
+        :meth:`_record` books: (plan info, bucket, microbatch split,
+        compiled now, step seconds, plan seconds)."""
         tel = self.telemetry
         tracer = tel.tracer
-        batch = self._prepare(batch)
         t0 = time.perf_counter()
         with tracer.span("plan", TRACK_STEP):
             mask, info = self.planner.plan(params, batch)
@@ -465,13 +490,7 @@ class Trainer:
         attempt = 0
         while True:
             k = max(int(getattr(info.plan, "microbatch", 1)), 1)
-            t_c0 = time.perf_counter()
-            fn, is_new = self._get_step_fn(mask, batch, k)
-            if is_new:
-                tracer.complete("build_step", t_c0,
-                                time.perf_counter() - t_c0, TRACK_STEP,
-                                args={"bucket": bucket}
-                                if tel.trace_on else None)
+            fn, is_new = self._get_step_fn(mask, batch, k, bucket)
             if self.transfer_lane is not None:
                 self.transfer_lane.reset_stats()
             t1 = time.perf_counter()
@@ -481,16 +500,18 @@ class Trainer:
                     # donated buffer is consumed by a simulated failure
                     wd.maybe_inject(step=self.global_step, bucket=bucket)
                 with self._mesh_ctx(), tracer.span("execute", TRACK_STEP):
-                    if isinstance(fn, tuple) and fn[0] == "opt_split":
-                        params, opt_state, loss, metrics = \
-                            self._run_opt_split(fn, params, opt_state,
-                                                batch)
-                    else:
-                        params, opt_state, loss, metrics = fn(
-                            params, opt_state, batch)
+                    with tracer.span("dispatch", TRACK_STEP):
+                        if isinstance(fn, tuple) and fn[0] == "opt_split":
+                            params, opt_state, loss, metrics = \
+                                self._run_opt_split(fn, params, opt_state,
+                                                    batch)
+                        else:
+                            params, opt_state, loss, metrics = fn(
+                                params, opt_state, batch)
                     # device sync: an async allocation failure surfaces
                     # here, inside the try, not on a later unrelated line
-                    loss = float(loss)
+                    with tracer.span("sync", TRACK_STEP):
+                        loss = float(loss)
             except Exception as e:
                 if wd is None or not wd.is_oom(e):
                     raise
@@ -519,6 +540,15 @@ class Trainer:
         if wd is not None and attempt:
             wd.on_retry_success()
         t_step = time.perf_counter() - t1
+        ctx = (info, bucket, k, is_new, t_step, t_plan)
+        return params, opt_state, loss, metrics, ctx
+
+    def _record(self, params, opt_state: AdamWState, loss: float, metrics,
+                batch, ctx) -> None:
+        """Counters, ``StepStats``, events and snapshots of a finished
+        step."""
+        tel = self.telemetry
+        info, bucket, k, is_new, t_step, t_plan = ctx
         eff_tokens = int(metrics["tokens"])
         padded_tokens = int(np.prod(np.shape(batch["tokens"])))
         if k > 1:
@@ -594,7 +624,6 @@ class Trainer:
             self.snapshots.save(step=self.global_step, params=params,
                                 opt_state=opt_state, planner=self.planner,
                                 data_cursor=self.data_cursor)
-        return params, opt_state, loss
 
     def run(self, params, batches, opt_state: Optional[AdamWState] = None):
         if opt_state is None:

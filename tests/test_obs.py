@@ -302,6 +302,147 @@ def test_trainer_telemetry_end_to_end(small, tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# spans over the whole step, and their forwarding to jax.profiler
+# ---------------------------------------------------------------------------
+
+def _inside(child, parent):
+    return (parent["ts"] <= child["ts"]
+            and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"])
+
+
+def test_step_spans_cover_the_step(small):
+    """Over warm steps every host stretch of ``Trainer.step`` has a span:
+    prepare/plan/execute/record inside step, dispatch/sync inside
+    execute, and the children cover at least 80% of the steps' time."""
+    _, lm, params = small
+    tracer = SpanTracer()
+    planner = MimosePlanner(lm, budget_bytes=1e12, quantum=8,
+                            warmup_samples=1)
+    tr = Trainer(lm, planner, AdamW(), telemetry=Telemetry(tracer=tracer))
+    p = jax.tree_util.tree_map(jnp.copy, params)
+    opt_state = tr.optimizer.init(p)
+    p, opt_state, _ = tr.step(p, opt_state, _batch(32))    # compiles
+    n_cold = len(tracer)
+    for _ in range(5):
+        p, opt_state, _ = tr.step(p, opt_state, _batch(32))
+    xs = [e for e in tracer.events()[n_cold:]
+          if e["ph"] == "X" and e["tid"] == TRACK_STEP]
+    steps = [e for e in xs if e["name"] == "step"]
+    assert len(steps) == 5
+    covered = 0.0
+    for st in steps:
+        inner = [e for e in xs if e is not st and _inside(e, st)]
+        names = [e["name"] for e in inner]
+        for name in ("prepare", "plan", "execute", "record", "dispatch",
+                     "sync"):
+            assert names.count(name) == 1, (name, names)
+        assert "build_step" not in names          # a cache hit
+        ex = next(e for e in inner if e["name"] == "execute")
+        for name in ("dispatch", "sync"):
+            assert _inside(next(e for e in inner if e["name"] == name), ex)
+        covered += sum(e["dur"] for e in inner
+                       if e["name"] in ("prepare", "plan", "execute",
+                                        "record"))
+    assert covered >= 0.8 * sum(st["dur"] for st in steps)
+    # the cold step built its step function inside its step span
+    cold = [e for e in tracer.events()[:n_cold] if e["ph"] == "X"]
+    cold_step = next(e for e in cold if e["name"] == "step")
+    build = next(e for e in cold if e["name"] == "build_step")
+    assert _inside(build, cold_step)
+
+
+def test_step_span_disabled_and_default_paths():
+    """The disabled path stays the shared no-op, and forwarding to the
+    profiler is off unless asked for."""
+    assert NullTracer().step_span("step", 3) is NULL_SPAN
+    tr = SpanTracer()
+    assert not tr.to_profiler
+    with tr.step_span("step", 3):
+        pass
+    assert [e["name"] for e in tr.events() if e["ph"] == "X"] == ["step"]
+
+
+def test_spans_forwarded_to_the_profiler(tmp_path):
+    """With ``to_profiler`` on, the spans are ``program:<name>``
+    annotations on the profiler's host plane, nested as they were, and
+    the step span is a ``StepTraceAnnotation`` with its step number."""
+    from jax.profiler import ProfileData
+
+    tr = SpanTracer(to_profiler=True)
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.step_span("step", 7):
+            with tr.span("plan", TRACK_STEP):
+                jnp.ones(8).sum().block_until_ready()
+    # the perf_counter record is kept as without forwarding
+    assert [e["name"] for e in tr.events() if e["ph"] == "X"] == \
+        ["plan", "step"]
+    paths = list(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    assert len(paths) == 1
+    host = {}
+    for plane in ProfileData.from_file(str(paths[0])).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    host.setdefault(e.name, []).append(
+                        (e.start_ns, e.end_ns,
+                         dict(e.stats) if e.name == "train" else None))
+    assert len(host["program:step"]) == 1 and len(host["program:plan"]) == 1
+    (s0, s1, _), = host["program:step"]
+    (p0, p1, _), = host["program:plan"]
+    assert s0 <= p0 and p1 <= s1
+    (t0, t1, stats), = host["train"]
+    assert stats.get("step_num") == 7 and t0 <= s0 and s1 <= t1
+
+
+def test_train_profile_dir_names_the_steps(monkeypatch, tmp_path):
+    """``launch/train.py --profile-dir`` writes a profiler trace whose
+    host plane holds the trainer's spans under the ``program:`` prefix
+    the benchmark's trace reduction reads."""
+    from jax.profiler import ProfileData
+
+    from repro.launch import compile_cache, train
+
+    monkeypatch.setenv(compile_cache.CACHE_ENV, str(tmp_path / "cache"))
+    prof = tmp_path / "profile"
+    train.main(["--reduced", "--steps", "2", "--batch-size", "2",
+                "--quantum", "64", "--profile-dir", str(prof)])
+    paths = list(prof.glob("plugins/profile/*/*.xplane.pb"))
+    assert len(paths) == 1
+    names = [e.name for plane in ProfileData.from_file(str(paths[0])).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events]
+    assert names.count("program:step") == 2
+    for name in ("program:prepare", "program:plan", "program:dispatch",
+                 "program:sync", "program:record"):
+        assert name in names, name
+
+
+def test_step_carries_named_scopes(small):
+    """The compiled step's ops carry a scope path: plan unit, attention
+    and MLP, output head, optimizer — and the backward and recompute
+    wrappers around them."""
+    from repro.actions import Action
+
+    _, lm, params = small
+    planner = MimosePlanner(lm, budget_bytes=1e12, quantum=8,
+                            warmup_samples=1)
+    tr = Trainer(lm, planner, AdamW())
+    batch = tr._prepare(_batch(32))
+    fwd = jax.jit(lm.loss).lower(params, batch).as_text(debug_info=True)
+    for scope in ("unit0/attn", "unit0/mlp", "head/"):
+        assert scope in fwd, scope
+    # unit 0 recomputed, the rest kept
+    mask = (Action.REMAT,) + (Action.KEEP,) * (lm.num_plan_units() - 1)
+    text = tr._build_step(mask).lower(
+        params, tr.optimizer.init(params), batch).as_text(debug_info=True)
+    for scope in ("jit(train_step)/optimizer/", "jvp(unit1)/attn/",
+                  "transpose(jvp(unit1))/mlp/",
+                  "jvp(unit0)/checkpoint/rematted_computation/attn/",
+                  "transpose(jvp(head))/"):
+        assert scope in text, scope
+
+
+# ---------------------------------------------------------------------------
 # tools/trace_view.py CLI
 # ---------------------------------------------------------------------------
 
