@@ -206,6 +206,7 @@ def bench_engine(smoke: bool) -> dict:
         t0 = time.perf_counter()
         for b in batches:
             p, opt_state, _ = tr.step(p, opt_state, b)
+        tr.drain()
         wall = time.perf_counter() - t0
         s = tr.summary()
         results[kind] = {

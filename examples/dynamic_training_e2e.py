@@ -45,9 +45,10 @@ t0 = time.time()
 for i, batch in enumerate(make_batches(
         "qqp", batch_size=args.batch_size, vocab_size=cfg.vocab_size,
         num_batches=args.steps, quantum=64, seed=0)):
-    params, opt_state, loss = trainer.step(params, opt_state, batch)
+    params, opt_state, _ = trainer.step(params, opt_state, batch)
     if i % 10 == 0:
         st = trainer.history[-1]
+        loss = st.loss    # reads the loss, and with it the step time
         print(f"step {i:4d}  loss {loss:7.4f}  S={batch['tokens'].shape[1]:4d}"
               f"  remat {st.remat_units:2d}/12  {st.step_time_s:6.2f}s"
               f"  plan {1e3 * st.plan_time_s:7.2f}ms")

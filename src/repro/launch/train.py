@@ -304,12 +304,14 @@ def main(argv=None):
         opts.host_tracer_level = 2
         jax.profiler.start_trace(args.profile_dir, profiler_options=opts)
     for i, batch in enumerate(batches):
-        params, opt_state, loss = trainer.step(params, opt_state, batch)
+        params, opt_state, _ = trainer.step(params, opt_state, batch)
         if i % 10 == 0 or i == args.steps - 1:
             st = trainer.history[-1]
+            loss = st.loss    # reads the loss, and with it the step time
             print(f"step {i:4d} loss {loss:.4f} S={batch['tokens'].shape[1]}"
                   f" remat={st.remat_units} offload={st.offload_units}"
                   f" k={st.microbatches} step_s={st.step_time_s:.3f}")
+    trainer.drain()
     if args.profile_dir:
         jax.profiler.stop_trace()
         print(f"profile written to {args.profile_dir}")

@@ -29,6 +29,11 @@ The trainer is the execution half of the *compile-once bucketed engine*:
      accumulation, so loss/grads match the full-batch step exactly.
      The jit-step cache key includes ``k``; ``StepStats.microbatches``
      and ``summary()['mean_microbatches']`` report where it kicked in.
+  6. ``step`` dispatches a step before it reads the loss of the one
+     before it, so the host's work around a step overlaps the chip's
+     work on the previous one.  The effective token count comes from
+     the host batch, so that loss is the only value a warm step reads
+     back from the device.
 
 Sharding: pass ``mesh`` to build and run every step under that Mesh
 context (required for ``with_sharding_constraint`` in the model).  The
@@ -42,7 +47,7 @@ import contextlib
 import dataclasses
 import time
 from collections.abc import MutableMapping
-from typing import Any, Dict, Iterable, Optional, Tuple
+from typing import Any, Iterable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -58,8 +63,27 @@ from repro.train.accumulate import accumulated_grads, build_accumulated_step
 from repro.train.transfer import TransferLane
 
 
+def effective_tokens(batch) -> int:
+    """The effective tokens of a padded host batch as ``lm.loss`` counts
+    them (its ``metrics["tokens"]``): ``max(sum(weights), 1)``, every
+    label position when the batch has no weights."""
+    w = batch.get("weights")
+    if w is None:
+        return max(int(np.prod(np.shape(batch.get("labels",
+                                                  batch["tokens"])))), 1)
+    return int(max(float(np.sum(np.asarray(w), dtype=np.float64)), 1.0))
+
+
+class DeferredStepError(RuntimeError):
+    """A step failed on the device, seen only when its loss was read one
+    step late; the message names that step.  It is not retried: the
+    step's donated inputs are gone."""
+
+
 @dataclasses.dataclass
 class StepStats:
+    # filled when the step's loss is read: by the next ``Trainer.step``,
+    # ``Trainer.drain`` or the first read of ``loss`` (see _ReadsPending)
     loss: float
     step_time_s: float
     plan_time_s: float
@@ -85,6 +109,36 @@ class StepStats:
     # the planner's predicted per-device peak under this step's plan:
     # fixed bytes plus the activation bytes the plan keeps on the device
     planned_peak_bytes: float = 0.0
+
+
+class _ReadsPending:
+    """``StepStats.loss``: while the step's loss is still on the device,
+    reading it reads the loss first (``_settle``, set by the trainer)."""
+
+    def __get__(self, obj, owner=None):
+        if obj is None:
+            return self
+        settle = obj.__dict__.get("_settle")
+        if settle is not None:
+            settle()
+        return obj.__dict__["loss"]
+
+    def __set__(self, obj, value):
+        obj.__dict__["loss"] = value
+
+
+# installed after the dataclass is built, so ``loss`` stays its first
+# positional field and dataclasses.asdict / repr read it through this
+StepStats.loss = _ReadsPending()
+
+
+class _Pending(NamedTuple):
+    """A dispatched step whose loss has not been read yet."""
+    step: int
+    loss: Any                  # 0-d device array
+    stats: StepStats
+    t_start: float             # perf_counter at its dispatch
+    event: Optional[dict]      # its ``train_step`` event, less the loss
 
 
 class Trainer:
@@ -131,6 +185,10 @@ class Trainer:
         # compiled executable per rare bucket forever
         self._step_cache = LRUCache(max_cached_steps)
         self.history: list[StepStats] = []
+        # the step dispatched last, its loss not read yet, and the
+        # perf_counter at which the loss before it was read
+        self._pending: Optional[_Pending] = None
+        self._t_read = 0.0
         reg = self.telemetry.metrics
         # per bucket: padded vs effective tokens (where the padding
         # waste went — launch/report.engine_report) and the largest
@@ -145,6 +203,9 @@ class Trainer:
             "largest gradient-accumulation split seen per bucket")
         self._h_step_s = reg.histogram(
             "train_step_time_s", "wall time per executed train step")
+        self._m_deferred = reg.counter(
+            "train_loss_reads_deferred",
+            "steps whose loss was read after the next step's dispatch")
         self.cache_stats = StatsView(
             reg,
             scalars={"compiles": "train_jit_compiles",
@@ -204,8 +265,8 @@ class Trainer:
                              str(getattr(v, "dtype", "")))
                             for k, v in batch.items() if k != "lengths"))
 
-    def _prepare(self, batch) -> dict:
-        """Bucket-pad and device-put one batch.
+    def _pad(self, batch) -> dict:
+        """Bucket-pad one batch on the host.
 
         The true ``lengths`` stay in the batch (defaulted to the full
         sequence when absent) so the model can thread them into the
@@ -218,9 +279,17 @@ class Trainer:
         if "lengths" not in batch:
             batch = dict(batch)
             batch["lengths"] = np.full((B,), S, np.int32)
+        return batch
+
+    @staticmethod
+    def _put(batch) -> dict:
         return {k: jnp.asarray(np.asarray(v, np.int32) if k == "lengths"
                                else v)
                 for k, v in batch.items()}
+
+    def _prepare(self, batch) -> dict:
+        """Bucket-pad and device-put one batch."""
+        return self._put(self._pad(batch))
 
     def _build_step(self, mask, microbatch: int = 1):
         opt = self.optimizer
@@ -457,27 +526,81 @@ class Trainer:
 
     # ------------------------------------------------------------------
     def step(self, params, opt_state: AdamWState, batch) -> tuple:
-        """One training step.  Every host stretch of it is a span on
-        ``TRACK_STEP`` inside the outer ``step``: ``prepare``, ``plan``,
-        ``build_step`` (a step-cache miss), ``execute`` (its ``dispatch``
-        and the ``sync`` on the loss) and ``record`` (the bookkeeping
-        after the step), so a profiler trace names the device time the
-        host leaves idle."""
+        """One training step, its loss read one step late.
+
+        The step is dispatched first; only then does the host wait for
+        the chip, on the loss of the step before it, so the chip has
+        this step queued through that wait and through the host work
+        that follows it.  Returns the new params and optimizer state and
+        this step's loss as an unread 0-d device array.
+        ``history[-1]`` describes this step on return; its ``loss`` and
+        ``step_time_s`` fill when the loss is read: by the next step,
+        by :meth:`drain`, or on the first read of ``history[-1].loss``.
+
+        Every host stretch is a span on ``TRACK_STEP`` inside the outer
+        ``step``: ``prepare``, ``plan``, ``build_step`` (a step-cache
+        miss), ``execute`` (its ``dispatch``, then the ``sync`` that
+        reads the previous step's loss) and ``record`` (the bookkeeping
+        after the dispatch), so a profiler trace names the device time
+        the host leaves idle."""
         tracer = self.telemetry.tracer
         with tracer.step_span("step", self.global_step):
             with tracer.span("prepare", TRACK_STEP):
-                batch = self._prepare(batch)
-            params, opt_state, loss, metrics, ctx = self._execute(
+                batch = self._pad(batch)
+                tokens = effective_tokens(batch)
+                batch = self._put(batch)
+            params, opt_state, loss, ctx = self._execute(
                 params, opt_state, batch)
             with tracer.span("record", TRACK_STEP):
-                self._record(params, opt_state, loss, metrics, batch, ctx)
+                self._record(params, opt_state, loss, tokens, batch, ctx)
         return params, opt_state, loss
 
+    def drain(self) -> None:
+        """Read the loss of the last dispatched step and finish its
+        bookkeeping: ``StepStats.loss`` and ``step_time_s``, the
+        ``train_step`` event.  Raises :class:`DeferredStepError` if that
+        step failed on the device."""
+        self._sync(deferred=False)
+
+    def _sync(self, deferred: bool) -> None:
+        """Read the pending step's loss in a ``sync`` span (nothing when
+        no step is pending).  ``deferred``: the next step is already
+        dispatched (``train_loss_reads_deferred``)."""
+        p = self._pending
+        if p is None:
+            return
+        self._pending = None
+        st = p.stats
+        del st._settle
+        tel = self.telemetry
+        with tel.tracer.span("sync", TRACK_STEP,
+                             args={"step": p.step} if tel.trace_on
+                             else None):
+            try:
+                loss = float(p.loss)
+            except Exception as e:
+                raise DeferredStepError(
+                    f"step {p.step} failed on the device: {e}") from e
+        t_read = time.perf_counter()
+        # from the previous loss read, so summary()'s rate stays paced
+        # by the chip; from its own dispatch when the chip had nothing
+        # before it (the first step, a compile, a pause in the caller)
+        st.step_time_s = t_read - (p.t_start if st.compile
+                                   else max(p.t_start, self._t_read))
+        st.loss = loss
+        self._t_read = t_read
+        self._h_step_s.observe(st.step_time_s)
+        if deferred:
+            self._m_deferred.inc()
+        if p.event is not None:
+            tel.events.emit("train_step", loss=loss,
+                            step_time_s=st.step_time_s, **p.event)
+
     def _execute(self, params, opt_state: AdamWState, batch) -> tuple:
-        """Plan, build or look up the step, run it and wait for its loss,
-        under the OOM watchdog's retry loop.  ``ctx`` carries what
-        :meth:`_record` books: (plan info, bucket, microbatch split,
-        compiled now, step seconds, plan seconds)."""
+        """Plan, build or look up the step and dispatch it under the OOM
+        watchdog's retry loop, then read the previous step's loss.
+        ``ctx`` carries what :meth:`_record` books: (plan info, bucket,
+        microbatch split, compiled now, dispatch start, plan seconds)."""
         tel = self.telemetry
         tracer = tel.tracer
         t0 = time.perf_counter()
@@ -491,6 +614,10 @@ class Trainer:
         while True:
             k = max(int(getattr(info.plan, "microbatch", 1)), 1)
             fn, is_new = self._get_step_fn(mask, batch, k, bucket)
+            if is_new:
+                # a compile far outlasts the step in flight: read that
+                # step first, so its time holds none of the compile
+                self._sync(deferred=False)
             if self.transfer_lane is not None:
                 self.transfer_lane.reset_stats()
             t1 = time.perf_counter()
@@ -502,18 +629,19 @@ class Trainer:
                 with self._mesh_ctx(), tracer.span("execute", TRACK_STEP):
                     with tracer.span("dispatch", TRACK_STEP):
                         if isinstance(fn, tuple) and fn[0] == "opt_split":
-                            params, opt_state, loss, metrics = \
+                            params, opt_state, loss, _ = \
                                 self._run_opt_split(fn, params, opt_state,
                                                     batch)
                         else:
-                            params, opt_state, loss, metrics = fn(
+                            params, opt_state, loss, _ = fn(
                                 params, opt_state, batch)
-                    # device sync: an async allocation failure surfaces
-                    # here, inside the try, not on a later unrelated line
-                    with tracer.span("sync", TRACK_STEP):
-                        loss = float(loss)
+                    # this step is queued: now wait on the loss of the
+                    # one before it.  A failure there is that step's,
+                    # raised as DeferredStepError and never retried
+                    self._sync(deferred=True)
             except Exception as e:
-                if wd is None or not wd.is_oom(e):
+                if wd is None or isinstance(e, DeferredStepError) \
+                        or not wd.is_oom(e):
                     raise
                 # the plan predicted this bucket fits; reality disagreed —
                 # book it (ONE bump of the shared train_oom_events
@@ -539,17 +667,16 @@ class Trainer:
             break
         if wd is not None and attempt:
             wd.on_retry_success()
-        t_step = time.perf_counter() - t1
-        ctx = (info, bucket, k, is_new, t_step, t_plan)
-        return params, opt_state, loss, metrics, ctx
+        ctx = (info, bucket, k, is_new, t1, t_plan)
+        return params, opt_state, loss, ctx
 
-    def _record(self, params, opt_state: AdamWState, loss: float, metrics,
+    def _record(self, params, opt_state: AdamWState, loss, eff_tokens: int,
                 batch, ctx) -> None:
-        """Counters, ``StepStats``, events and snapshots of a finished
-        step."""
+        """Counters, ``StepStats`` and snapshots of a dispatched step,
+        which becomes the pending one: its loss, step time and
+        ``train_step`` event wait for :meth:`_sync`."""
         tel = self.telemetry
-        info, bucket, k, is_new, t_step, t_plan = ctx
-        eff_tokens = int(metrics["tokens"])
+        info, bucket, k, is_new, t_start, t_plan = ctx
         padded_tokens = int(np.prod(np.shape(batch["tokens"])))
         if k > 1:
             # a non-divisor split pads the batch axis to ceil(B/k)*k
@@ -561,7 +688,6 @@ class Trainer:
         self._m_padded_tokens.inc(padded_tokens, bucket=bucket)
         self._m_eff_tokens.inc(eff_tokens, bucket=bucket)
         self._g_bucket_k.set_max(k, bucket=bucket)
-        self._h_step_s.observe(t_step)
         # transfer telemetry: what the lane measured this step vs what
         # the simulator's (1 - overlap) pricing predicts for the SAME
         # bytes — the bench gate holds the pair to a tolerance band
@@ -595,29 +721,32 @@ class Trainer:
             float(info.plan.est_activation_bytes)
             - float(info.plan.covered_bytes)
         ) / self.planner.activation_divisor_scalar()
-        self.history.append(StepStats(loss, t_step, t_plan, is_new,
-                                      info.plan.n_remat, eff_tokens, bucket,
-                                      padded_tokens,
-                                      offload_units=info.plan.n_offload,
-                                      microbatches=k,
-                                      opt_offload_units=getattr(
-                                          info.plan, "n_opt", 0),
-                                      offload_degraded=degraded,
-                                      exposed_transfer_s=exposed_s,
-                                      sim_transfer_s=sim_s,
-                                      planned_peak_bytes=planned_peak))
+        stats = StepStats(float("nan"), float("nan"), t_plan, is_new,
+                          info.plan.n_remat, eff_tokens, bucket,
+                          padded_tokens,
+                          offload_units=info.plan.n_offload,
+                          microbatches=k,
+                          opt_offload_units=getattr(info.plan, "n_opt", 0),
+                          offload_degraded=degraded,
+                          exposed_transfer_s=exposed_s,
+                          sim_transfer_s=sim_s,
+                          planned_peak_bytes=planned_peak)
+        stats._settle = self.drain
+        self.history.append(stats)
+        event = None
         if tel.events_on:
-            tel.events.emit("train_step", step=self.global_step,
-                            bucket=bucket, loss=loss, k=k,
-                            compile=bool(is_new),
-                            plan_source=info.plan.source,
-                            cache_hit=bool(info.cache_hit),
-                            n_remat=int(info.plan.n_remat),
-                            n_offload=int(info.plan.n_offload),
-                            step_time_s=t_step, plan_time_s=t_plan,
-                            exposed_transfer_s=exposed_s,
-                            predicted_peak_bytes=fixed + float(
-                                info.plan.est_activation_bytes))
+            event = dict(step=self.global_step, bucket=bucket, k=k,
+                         compile=bool(is_new),
+                         plan_source=info.plan.source,
+                         cache_hit=bool(info.cache_hit),
+                         n_remat=int(info.plan.n_remat),
+                         n_offload=int(info.plan.n_offload),
+                         plan_time_s=t_plan,
+                         exposed_transfer_s=exposed_s,
+                         predicted_peak_bytes=fixed + float(
+                             info.plan.est_activation_bytes))
+        self._pending = _Pending(self.global_step, loss, stats, t_start,
+                                 event)
         self.global_step += 1
         self.data_cursor += 1
         if self.snapshots is not None and self.snapshots.due(self.global_step):
@@ -629,11 +758,13 @@ class Trainer:
         if opt_state is None:
             opt_state = self.optimizer.init(params)
         for batch in batches:
-            params, opt_state, loss = self.step(params, opt_state, batch)
+            params, opt_state, _ = self.step(params, opt_state, batch)
+        self.drain()
         return params, opt_state
 
     # ------------------------------------------------------------------
     def summary(self) -> dict:
+        self.drain()
         h = self.history
         if not h:
             return {}

@@ -285,6 +285,9 @@ def test_trainer_telemetry_end_to_end(small, tmp_path):
     for _ in range(3):
         p, opt_state, loss = tr.step(p, opt_state, _batch(32))
         assert np.isfinite(loss)
+    # a step's train_step event carries its loss, so it is written when
+    # that loss is read: the last one by the drain
+    tr.drain()
     flush_telemetry(tel)
     steps = [r for r in read_events(ep) if r["kind"] == "train_step"]
     assert len(steps) == 3
@@ -313,7 +316,8 @@ def _inside(child, parent):
 def test_step_spans_cover_the_step(small):
     """Over warm steps every host stretch of ``Trainer.step`` has a span:
     prepare/plan/execute/record inside step, dispatch/sync inside
-    execute, and the children cover at least 80% of the steps' time."""
+    execute (``sync`` reads the previous step's loss, after this step's
+    dispatch), and the children cover at least 80% of the steps' time."""
     _, lm, params = small
     tracer = SpanTracer()
     planner = MimosePlanner(lm, budget_bytes=1e12, quantum=8,
