@@ -3,7 +3,7 @@
 Training: each checked step's loss, the first gradient as the optimizer
 got it, and the parameters' change after the checked steps, each as
 the widest relative gap of a per-leaf norm (a leaf is one layer's
-matrix or vector in the canonical layout of ``weights.py``).  The gap of
+matrix or vector in the reference's canonical layout).  The gap of
 one leaf is ``|norm_program - norm_reference|`` over the larger of the
 reference leaf's norm and the median leaf's.
 
@@ -25,15 +25,21 @@ STILL_LEAF_SHARE = 1e-3
 
 
 def leaf_norms(canon: dict) -> dict:
-    """``{name: norm}`` with one entry per layer for stacked leaves."""
-    out = {"embed": float(jnp.linalg.norm(canon["embed"].astype(jnp.float32))),
-           "final_norm": float(jnp.linalg.norm(
-               canon["final_norm"].astype(jnp.float32)))}
-    for name, a in canon["layers"].items():
+    """``{name: norm}`` of a tree in a reference's canonical layout: one
+    entry per leaf, named by its dotted path, and one per layer
+    (``layers.<i>.<path>``) for each leaf under ``"layers"``, which is
+    stacked on a leading layer axis."""
+    out = {}
+    for path, a in jax.tree_util.tree_flatten_with_path(canon)[0]:
+        keys = [str(getattr(k, "key", getattr(k, "idx", k))) for k in path]
         a = a.astype(jnp.float32)
+        if keys[0] != "layers":
+            out[".".join(keys)] = float(jnp.linalg.norm(a))
+            continue
         n = np.asarray(jnp.sqrt(jnp.sum(a * a, axis=tuple(range(1, a.ndim)))))
+        rest = ".".join(keys[1:])
         for i, v in enumerate(n):
-            out[f"layers.{i}.{name}"] = float(v)
+            out[f"layers.{i}.{rest}"] = float(v)
     return out
 
 

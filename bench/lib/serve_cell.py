@@ -21,9 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.lib import spans
-from bench.lib.weights import make_weights
-from bench.reference import dense_lm
+from bench.lib import reference, spans
 from bench.traffic import gen
 
 TRACE_SECONDS = 3.0
@@ -85,23 +83,23 @@ def _requests(cell):
                 seed=cell.seed, seconds=cell.seconds)]
 
 
-def reference_gaps(cfg, seed, samples, *, precision="fp32", pad_to=None,
-                   control=None):
+def reference_gaps(ref, cfg, seed, samples, *, pad_to=None, control=None):
     """Per sampled request, the gap of each served token's reference
-    logit below the reference's best.  ``control`` ("int8" or "bf16")
-    instead reads the gap of the token that the lower precision puts
-    first at each of those positions."""
+    logit below the reference's best, from the configuration's reference
+    module ``ref``.  ``control`` (a precision of that module, such as
+    "int8") instead reads the gap of the token that the lower precision
+    puts first at each of those positions."""
     m = cfg["model"]
-    params = make_weights(seed, m, program=False)
+    params = ref.make_weights(seed, m, program=False)
     pad = pad_to or max(len(p) + len(t) for p, t in samples)
 
     def fn(params, tokens, length, served):
-        lg = dense_lm.logits(params, tokens, length, m, "fp32")[0]
+        lg = ref.logits(params, tokens, length, m, "fp32")[0]
         best = jnp.max(lg, axis=-1)
         if control is None:
             pick = served
         else:
-            lo = dense_lm.logits(params, tokens, length, m, control)[0]
+            lo = ref.logits(params, tokens, length, m, control)[0]
             pick = jnp.argmax(lo, axis=-1)
         return best - jnp.take_along_axis(lg, pick[:, None], -1)[:, 0]
 
@@ -137,12 +135,13 @@ def check_samples(done, seed):
 
 def run(cell) -> dict:
     cfg, m = cell.cfg, cell.cfg["model"]
+    ref, ctrl = cell.ref, reference.control(cell.cfg)
     device = cell.devices[0]
     hbm = float(device.memory_stats()["bytes_limit"]) \
         if device.platform == "tpu" else float(cfg["serve"]["hbm_bytes"])
     q = cfg["serve"]["quantum"]
     lm = _build(cfg)
-    params = make_weights(cell.seed, m)
+    params = ref.make_weights(cell.seed, m)
     want = jax.tree_util.tree_structure(
         jax.eval_shape(lm.init, jax.random.PRNGKey(0)))
     if jax.tree_util.tree_structure(params) != want:
@@ -218,8 +217,9 @@ def run(cell) -> dict:
         if trace_summary is not None:
             trace_summary["host_window_s"] = state["t"][1] - state["t"][0]
 
-    gaps = reference_gaps(cfg, cell.seed, samples,
-                          pad_to=_pad_len(cell.traffic, q))
+    gaps = reference_gaps(
+        ref, cell.cfg, cell.seed, samples, pad_to=_pad_len(cell.traffic, q),
+        control=ctrl["precision"] if cell.control else None)
     numbers = {"token_gap": float(max((g.max() for g in gaps if len(g)),
                                       default=float("inf"))),
                "_where": {"requests": len(samples),

@@ -5,7 +5,9 @@ every bucket the seeded feed can produce (plan and compile), then drives
 that same trainer through the first ``CHECK_STEPS`` steps of the feed,
 which the reference follows.  The window keeps calling the same
 ``Trainer.step`` on the same feed for ``seconds``; every step is taken
-whole, so the window ends with the first step that ends past it.
+whole, so the window ends with the first step that ends past it.  The
+weights, their layout and the reference come from the module the
+configuration names (``bench/lib/reference.py``).
 """
 from __future__ import annotations
 
@@ -17,9 +19,7 @@ import time
 import jax
 import numpy as np
 
-from bench.lib import check, spans
-from bench.lib.weights import from_program, make_weights
-from bench.reference import dense_lm
+from bench.lib import check, reference, spans
 from bench.traffic import gen
 
 CHECK_STEPS = 3
@@ -29,7 +29,8 @@ TRACE_SECONDS = 3.0
 class ProgramStepper:
     """The system under test: ``repro.train.trainer.Trainer``."""
 
-    def __init__(self, cfg: dict, budget_bytes: float, telemetry=None):
+    def __init__(self, cfg: dict, ref, budget_bytes: float,
+                 telemetry=None):
         import dataclasses
         from repro.core import MimosePlanner
         from repro.models.lm import build_model
@@ -53,6 +54,7 @@ class ProgramStepper:
         self.trainer = Trainer(self.lm, self.planner, self.opt,
                                telemetry=telemetry)
         self.model = m
+        self.ref = ref
 
     def check_layout(self, params) -> None:
         want = jax.tree_util.tree_structure(
@@ -74,7 +76,8 @@ class ProgramStepper:
     def first_grad(self, state):
         """The clipped gradient of step 1 as AdamW holds it (m / (1-b1))."""
         return jax.tree_util.tree_map(lambda a: a / (1 - self.b1),
-                                      from_program(state.m, self.model))
+                                      self.ref.from_program(state.m,
+                                                            self.model))
 
     def last_stats(self) -> dict:
         s = self.trainer.history[-1]
@@ -87,28 +90,33 @@ class ProgramStepper:
         return self.lm.num_plan_units()
 
 
-def reference_readings(cfg: dict, seed: int, batches: list, *,
-                       keep_rows=None) -> dict:
+def reference_readings(ref, cfg: dict, seed: int, batches: list, *,
+                       keep_rows=None, precision: str = "fp32") -> dict:
     """Losses, first clipped gradient and change after the checked steps,
-    of the plain reference from the seed's weights, as leaf norms."""
+    of the plain reference from the seed's weights, as leaf norms.  The
+    parameters, AdamW's state and the gradient summed over blocks stay on
+    the device, which the module's block sizes count; the first weights
+    are made again from the seed to read the change."""
     m, o = cfg["model"], cfg["train"]["adamw"]
-    opt = dense_lm.AdamW(lr=o["lr"], warmup=o["warmup"], total=o["total"],
-                         b1=o["b1"], b2=o["b2"], eps=o["eps"],
-                         weight_decay=o["weight_decay"],
-                         clip_norm=o["clip_norm"])
-    p0 = make_weights(seed, m, dtype="float32", program=False)
-    params, state = p0, opt.init(p0)
+    opt = ref.AdamW(lr=o["lr"], warmup=o["warmup"], total=o["total"],
+                    b1=o["b1"], b2=o["b2"], eps=o["eps"],
+                    weight_decay=o["weight_decay"], clip_norm=o["clip_norm"])
+    params = ref.make_weights(seed, m, dtype="float32", program=False)
+    state = opt.init(params)
     losses, grad = [], None
     with jax.default_matmul_precision("highest"):
         for i, b in enumerate(batches):
-            loss, g = dense_lm.loss_and_grad(params, b, m,
-                                             keep_rows=keep_rows)
+            loss, g = ref.loss_and_grad(params, b, m, keep_rows=keep_rows,
+                                        precision=precision)
             if i == 0:
                 grad = check.leaf_norms(opt.clip_grads(g))
             params, state = opt.update(g, state, params)
+            del g
             losses.append(float(loss))
-    return {"losses": losses, "grad": grad,
-            "change": check.diff_norms(params, p0)}
+        del state
+        change = check.diff_norms(params, ref.make_weights(
+            seed, m, dtype="float32", program=False))
+    return {"losses": losses, "grad": grad, "change": change}
 
 
 def checked_steps(stepper, step, params, state, feed, seed, m):
@@ -124,15 +132,17 @@ def checked_steps(stepper, step, params, state, feed, seed, m):
         prog["losses"].append(float(loss))
         if i == 0:
             prog["grad"] = check.leaf_norms(stepper.first_grad(state))
-    p0 = make_weights(seed, m)
-    prog["change"] = check.diff_norms(from_program(params, m),
-                                      from_program(p0, m))
+    ref = stepper.ref
+    p0 = ref.make_weights(seed, m)
+    prog["change"] = check.diff_norms(ref.from_program(params, m),
+                                      ref.from_program(p0, m))
     del p0
     return params, state, checked, prog
 
 
 def run(cell) -> dict:
     cfg, m, t = cell.cfg, cell.cfg["model"], cell.cfg["train"]
+    ref, ctrl = cell.ref, reference.control(cell.cfg)
     device = cell.devices[0]
     B, quantum = int(t["batch_size"]), int(t["quantum"])
     budget = float(device.memory_stats()["bytes_limit"]) \
@@ -143,13 +153,12 @@ def run(cell) -> dict:
         from repro.obs import Telemetry
         tracer = spans.tracer_class()()
         telemetry = Telemetry(tracer=tracer)
-    if cell.control:
-        # the control: the program's own next precision path, bfloat16
-        cfg = dict(cfg, model=dict(m, dtype="bfloat16"))
+    if cell.control and ctrl["on"] == "program":
+        cfg = dict(cfg, model=dict(m, dtype=ctrl["dtype"]))
         m = cfg["model"]
-    stepper = ProgramStepper(cfg, budget, telemetry)
+    stepper = ProgramStepper(cfg, ref, budget, telemetry)
 
-    params = make_weights(cell.seed, m)
+    params = ref.make_weights(cell.seed, m)
     stepper.check_layout(params)
     state = stepper.init_state(params)
     buckets = gen.train_buckets(cell.traffic, B, quantum)
@@ -213,8 +222,11 @@ def run(cell) -> dict:
         shutil.rmtree(log_dir, ignore_errors=True)
         if trace_summary is not None:
             trace_summary["host_window_s"] = traced[1] - traced[0]
-    ref = reference_readings(cell.cfg, cell.seed, checked)
-    numbers = check.train_numbers(prog, ref)
+    if cell.control and ctrl["on"] == "reference":
+        prog = reference_readings(ref, cell.cfg, cell.seed, checked,
+                                  precision=ctrl["precision"])
+    numbers = check.train_numbers(
+        prog, reference_readings(ref, cell.cfg, cell.seed, checked))
 
     window = t_end - t0
     eff = sum(r["tokens"] for r in records)

@@ -12,7 +12,8 @@ with RMSNorm n(x) = x / sqrt(mean(x^2) + eps) * scale computed in float32,
 rotary embeddings on the two halves of each head (theta from the config),
 tied embeddings as the output head, and a token-weighted mean
 cross-entropy.  Weights come in the canonical layout of
-``bench/lib/weights.py``.
+``bench/lib/weights.py``, which also gives ``make_weights`` and
+``from_program``; the optimizer is the shared ``adamw.AdamW``.
 
 ``precision`` picks the arithmetic:
 
@@ -20,7 +21,14 @@ cross-entropy.  Weights come in the canonical layout of
   ``Precision.HIGHEST`` -- the reference;
 * ``"int8"``: the fp32 path with every matrix input quantised to int8
   (weights per output channel, activations per row) -- the control of a
-  bfloat16 configuration that has no int8 path of its own.
+  bfloat16 configuration that has no int8 path of its own.  Its gradient
+  passes each quantiser straight through.
+
+The gradient is computed in blocks that fit the device (``block_sizes``):
+blocks of rows summed into one donated buffer, each layer of the scan
+under ``jax.checkpoint``, and the output head and loss over blocks of
+tokens, each under ``jax.checkpoint``, so no (rows, S, V) logits tensor
+exists.  Blocking only reorders float32 sums.
 
 The GELU is the tanh form: the configuration's ``mlp_act: gelu`` names
 the model this repository defines, whose activation is that form.
@@ -32,11 +40,20 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
+
+from bench.lib.weights import from_program, layer_shapes, make_weights
+from bench.reference import blocks
+from bench.reference.adamw import AdamW
+
+__all__ = ["AdamW", "from_program", "make_weights", "loss_and_grad",
+           "logits", "block_sizes"]
 
 HIGHEST = lax.Precision.HIGHEST
 
 
+@functools.partial(jax.custom_jvp, nondiff_argnums=(1,))
 def _q8(x, axis):
     """Symmetric int8 quantise-dequantise along ``axis`` (the reduced
     axis of the product), in float32."""
@@ -44,6 +61,12 @@ def _q8(x, axis):
     s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / 127.0
     s = jnp.where(s == 0, 1.0, s)
     return jnp.clip(jnp.round(x / s), -127, 127) * s
+
+
+@_q8.defjvp
+def _q8_jvp(axis, primals, tangents):
+    (x,), (t,) = primals, tangents
+    return _q8(x, axis), t.astype(jnp.float32)
 
 
 def _mm(a, w, precision: str):
@@ -112,6 +135,7 @@ def hidden(params, tokens, lengths, m: dict, precision: str = "fp32"):
     """Final-norm hidden states (B, S, d)."""
     x = params["embed"][tokens].astype(jnp.float32)
 
+    @functools.partial(jax.checkpoint, prevent_cse=False)
     def body(x, p):
         return block(x, p, lengths, m, precision), None
     x, _ = lax.scan(body, x, params["layers"])
@@ -123,21 +147,86 @@ def logits(params, tokens, lengths, m: dict, precision: str = "fp32"):
     return _mm(x, params["embed"].T, precision).astype(jnp.float32)
 
 
-def nll_sum(params, batch, m: dict, precision: str = "fp32"):
-    """Sum of token-weighted negative log-likelihoods of a batch."""
-    lg = logits(params, batch["tokens"], batch["lengths"], m, precision)
-    lse = jax.nn.logsumexp(lg, axis=-1)
-    lab = jnp.take_along_axis(lg, batch["labels"][..., None], -1)[..., 0]
-    return jnp.sum((lse - lab) * batch["weights"])
+def nll_sum(params, batch, m: dict, precision: str = "fp32",
+            tokens: int | None = None):
+    """Sum of token-weighted negative log-likelihoods of a batch, the
+    output head over blocks of ``tokens`` tokens (default: one block)."""
+    x = hidden(params, batch["tokens"], batch["lengths"], m, precision)
+    d = x.shape[-1]
+    x = x.reshape(-1, d)
+    lab, w = batch["labels"].reshape(-1), batch["weights"].reshape(-1)
+    n = x.shape[0]
+    t = n if tokens is None else min(int(tokens), n)
+    pad = -n % t
+    if pad:
+        x = jnp.concatenate([x, jnp.zeros((pad, d), x.dtype)])
+        lab = jnp.concatenate([lab, jnp.zeros((pad,), lab.dtype)])
+        w = jnp.concatenate([w, jnp.zeros((pad,), w.dtype)])
+
+    @functools.partial(jax.checkpoint, prevent_cse=False)
+    def head(total, blk):
+        xb, lb, wb = blk
+        lg = _mm(xb, params["embed"].T, precision).astype(jnp.float32)
+        lse = jax.nn.logsumexp(lg, axis=-1)
+        ll = jnp.take_along_axis(lg, lb[:, None], -1)[:, 0]
+        return total + jnp.sum((lse - ll) * wb), None
+    total, _ = lax.scan(head, jnp.zeros((), jnp.float32),
+                        (x.reshape(-1, t, d), lab.reshape(-1, t),
+                         w.reshape(-1, t)))
+    return total
+
+
+def block_sizes(m: dict, n_rows: int, S: int,
+                limit: float | None) -> tuple:
+    """(rows, tokens): the rows of one gradient block and the tokens of
+    one head block, from the configuration's shapes and the device's
+    ``bytes_limit`` (``limit``; None: one block of each).  Resident: the
+    parameters, AdamW's m and v, the summed gradient and one block's
+    gradient; per row: every layer's input kept for the backward, and
+    one layer's recomputed activations with their cotangents; per token
+    of the head: logits, probabilities and their cotangent.  The
+    estimate lies above what the compiler allots: for a described v5e,
+    qwen3_1p7b cut to 7 layers at 1 row of 2048 and 256 head tokens
+    needs 14.19 GB with m and v (estimate 15.15 GB), bert_base_paper at
+    48 rows of 512 and 8192 head tokens 6.13 GB (estimate 10.16 GB)."""
+    if limit is None:
+        return n_rows, n_rows * S
+    d, L, V, ff = m["d_model"], m["num_layers"], m["vocab_size"], m["d_ff"]
+    H, hd = m["num_heads"], m["head_dim"]
+    n_params = sum(math.prod(s) for s in layer_shapes(m).values()) \
+        + V * d + d
+    resident = blocks.F32 * 5 * n_params
+    mlp = (5 if m["mlp_act"] == "swiglu" else 4) * ff
+    per_row = blocks.F32 * S * (L * d + 3 * H * S + mlp + 6 * H * hd
+                                + 8 * d)
+    per_token = blocks.F32 * 3 * V
+    free = USABLE * limit - resident
+    if free < per_row + per_token:
+        raise MemoryError(
+            f"the reference needs {resident / 2**30:.2f} GiB resident and "
+            f"{per_row / 2**30:.2f} GiB a row; {USABLE} of the device's "
+            f"{limit / 2**30:.2f} GiB does not hold them")
+    tokens = min(n_rows * S, 1 << int(math.log2(
+        max(HEAD_SHARE * free / per_token, 1))))
+    rows = int((free - tokens * per_token) // per_row)
+    return max(min(rows, n_rows), 1), tokens
+
+
+# the share of ``bytes_limit`` the estimate may fill, and of what is left
+# after the resident trees, the share a head block may take
+USABLE = 0.9
+HEAD_SHARE = 0.25
 
 
 @functools.lru_cache(maxsize=None)
-def _grad_fn(model_items: tuple):
+def _grad_fn(model_items: tuple, precision: str, tokens: int):
     m = dict(model_items)
 
-    def f(params, batch):
-        return jax.value_and_grad(nll_sum)(params, batch, m)
-    return jax.jit(f)
+    def f(acc, params, batch):
+        s, g = jax.value_and_grad(nll_sum)(params, batch, m, precision,
+                                           tokens)
+        return jax.tree_util.tree_map(jnp.add, acc, g), s
+    return jax.jit(f, donate_argnums=0)
 
 
 def _items(m: dict) -> tuple:
@@ -145,65 +234,14 @@ def _items(m: dict) -> tuple:
                         if isinstance(v, (int, float, str, bool))))
 
 
-def loss_and_grad(params, batch, m: dict, *, rows: int = 8,
-                  keep_rows: int | None = None):
-    """Token-weighted mean loss of ``batch`` and its gradient, summed in
-    blocks of ``rows`` rows so that a batch larger than the device holds
-    at once still fits.  ``keep_rows`` keeps only the first rows (the
-    half-batch fault)."""
-    import numpy as np
-    n = int(np.shape(batch["tokens"])[0])
-    if keep_rows is not None:
-        n = keep_rows
-    total_w = float(np.maximum(np.sum(np.asarray(batch["weights"])[:n]), 1.0))
-    fn = _grad_fn(_items(m))
-    val, grad = 0.0, None
-    for r in range(0, n, rows):
-        sub = {k: jnp.asarray(np.asarray(v)[r:min(r + rows, n)])
-               for k, v in batch.items()}
-        s, g = fn(params, sub)
-        val += float(s)
-        grad = g if grad is None else jax.tree_util.tree_map(jnp.add, grad, g)
-    return val / total_w, jax.tree_util.tree_map(lambda a: a / total_w, grad)
-
-
-class AdamW:
-    """AdamW as published (Loshchilov & Hutter), with global-norm
-    clipping first and a linear-warmup cosine schedule."""
-
-    def __init__(self, *, lr, warmup, total, b1=0.9, b2=0.999, eps=1e-8,
-                 weight_decay=0.01, clip_norm=1.0):
-        self.lr, self.warmup, self.total = lr, warmup, total
-        self.b1, self.b2, self.eps = b1, b2, eps
-        self.wd, self.clip = weight_decay, clip_norm
-
-    def rate(self, step: int) -> float:
-        if step < self.warmup:
-            return self.lr * step / max(self.warmup, 1)
-        prog = min(max((step - self.warmup)
-                       / max(self.total - self.warmup, 1), 0.0), 1.0)
-        return self.lr * 0.5 * (1 + math.cos(math.pi * prog))
-
-    def init(self, params):
-        z = jax.tree_util.tree_map(jnp.zeros_like, params)
-        return {"step": 0, "m": z, "v": z}
-
-    def clip_grads(self, grads):
-        norm = jnp.sqrt(sum(jnp.sum(g * g)
-                            for g in jax.tree_util.tree_leaves(grads)))
-        scale = jnp.minimum(1.0, self.clip / (norm + 1e-9))
-        return jax.tree_util.tree_map(lambda g: g * scale, grads)
-
-    def update(self, grads, state, params):
-        t = state["step"] + 1
-        g = self.clip_grads(grads)
-        m = jax.tree_util.tree_map(lambda a, b: self.b1 * a + (1 - self.b1)
-                                   * b, state["m"], g)
-        v = jax.tree_util.tree_map(lambda a, b: self.b2 * a + (1 - self.b2)
-                                   * b * b, state["v"], g)
-        bc1, bc2, lr = 1 - self.b1 ** t, 1 - self.b2 ** t, self.rate(t)
-        new = jax.tree_util.tree_map(
-            lambda p, a, b: p - lr * ((a / bc1) / (jnp.sqrt(b / bc2)
-                                                   + self.eps)
-                                      + self.wd * p), params, m, v)
-        return new, {"step": t, "m": m, "v": v}
+def loss_and_grad(params, batch, m: dict, *, keep_rows: int | None = None,
+                  precision: str = "fp32"):
+    """Token-weighted mean loss of ``batch`` and its gradient, in the
+    blocks of rows and of head tokens that ``block_sizes`` sets from the
+    device's ``bytes_limit``.  ``keep_rows`` keeps only the first rows
+    (the half-batch fault)."""
+    B, S = np.shape(batch["tokens"])
+    n = B if keep_rows is None else keep_rows
+    rows, tokens = block_sizes(m, n, S, blocks.bytes_limit())
+    return blocks.sum_grads(_grad_fn(_items(m), precision, int(tokens)),
+                            params, batch, rows=rows, keep_rows=keep_rows)

@@ -6,7 +6,9 @@ Run from the root of a checkout that holds ``BENCHMARK.json``, this
 ``bench/`` directory and the program under ``src/``.  The cell's
 configuration, traffic mix and per-layer metrics are found by the names
 ``BENCHMARK.json`` gives them: ``bench/configs/<config>.json``,
-``bench/traffic/<traffic>.json`` and ``bench/metrics/<metric>.py``.
+``bench/traffic/<traffic>.json`` and ``bench/metrics/<metric>.py``; the
+plain reference by the name the configuration gives it,
+``bench/reference/<reference>.py``.
 
 With ``--trace 0`` the result carries the cell's end-to-end metrics,
 with ``--trace 1`` its per-layer metrics, read from a separate traced
@@ -85,16 +87,16 @@ def run_cell(args, *, require_tpu: bool = True, control: bool = False,
              clock0: float = CLOCK0) -> dict:
     """Everything a run does, returned as its result dict.  The command
     line always asks for a TPU; ``require_tpu=False`` (tests) takes the
-    default device instead; ``control`` runs a training cell on the
-    program's bfloat16 path, and ``fault`` plants a fault in the timed
-    path."""
+    default device instead; ``control`` runs the control the
+    configuration names (``bench/lib/reference.py``), and ``fault``
+    plants a fault in the timed path."""
     spec = spec if spec is not None else load_spec()
     w, cfg, traffic, e2e, layer = resolve(spec, args.workload, bench_dir)
     if not (ROOT / "src" / "repro").is_dir():
         raise FileNotFoundError(f"no program under {ROOT / 'src'}")
     if str(ROOT / "src") not in sys.path:
         sys.path.insert(0, str(ROOT / "src"))
-    from bench.lib import check, device as dev
+    from bench.lib import check, device as dev, reference
     import jax
     if require_tpu:
         dev.enable_compile_cache()
@@ -106,6 +108,7 @@ def run_cell(args, *, require_tpu: bool = True, control: bool = False,
         cfg=cfg, traffic=traffic, seed=args.seed, seconds=args.seconds,
         trace=bool(args.trace), devices=devices, clock0=clock0,
         counter=counter, control=control, fault=fault,
+        ref=reference.load(cfg, bench_dir),
         memory_peak=lambda: dev.memory_peak(devices))
     if cfg["kind"] == "train":
         from bench.lib import train_cell as driver

@@ -34,13 +34,12 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     w, cfg, traffic, _, _ = resolve(load_spec(), args.workload)
     sys.path.insert(0, str(ROOT / "src"))
-    from bench.lib import device as dev, serve_cell
-    from bench.lib.weights import make_weights
+    from bench.lib import device as dev, reference, serve_cell
     dev.enable_compile_cache()
     devices = dev.require_chips(int(w["chips"]))
     hbm = float(devices[0].memory_stats()["bytes_limit"])
     lm = serve_cell._build(cfg)
-    params = make_weights(args.seed, cfg["model"])
+    params = reference.load(cfg).make_weights(args.seed, cfg["model"])
     q = cfg["serve"]["quantum"]
     warmed = set()
     for rate in [float(r) for r in args.rates.split(",")]:

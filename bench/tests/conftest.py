@@ -63,6 +63,7 @@ def tiny_bench(tmp_path_factory) -> pathlib.Path:
     (d / "configs").mkdir()
     (d / "traffic").mkdir()
     (d / "metrics").symlink_to(BENCH / "metrics")
+    (d / "reference").symlink_to(BENCH / "reference")
     b = json.loads((BENCH / "configs/bert_base_paper.json").read_text())
     b["model"].update(TINY_MODEL, num_kv_heads=4)
     b["train"].update(batch_size=4, budget_bytes=1e18)

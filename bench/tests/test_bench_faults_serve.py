@@ -46,11 +46,13 @@ def test_altered_token_is_not_correct(tiny_bench):
 
 def test_int8_control_reads_above_the_limit():
     """At the published widths, two layers and a 16384-row vocabulary."""
+    from bench.lib import reference
     from bench.lib.serve_cell import reference_gaps
     cfg = json.loads((BENCH / "configs/qwen3_1p7b.json").read_text())
     cfg["model"].update(num_layers=2, vocab_size=16384)
     rng = np.random.default_rng(4)
     samples = [(rng.integers(1, 16384, n).astype(np.int32),
                 list(rng.integers(1, 16384, 40))) for n in (20, 31, 9)]
-    gaps = reference_gaps(cfg, 4, samples, control="int8")
+    gaps = reference_gaps(reference.load(cfg), cfg, 4, samples,
+                          control=reference.control(cfg)["precision"])
     assert max(float(g.max()) for g in gaps) > cfg["limits"]["token_gap"]

@@ -58,7 +58,10 @@ def test_weights_round_trip_the_program_layout():
 
 @pytest.mark.parametrize("arch,m", [("bert_base_paper", BERT_SMALL),
                                     ("qwen3-1.7b", QWEN_SMALL)])
-def test_forward_loss_and_grad_match_the_program(arch, m):
+def test_forward_loss_and_grad_match_the_program(arch, m, monkeypatch):
+    # the reference in blocks of 3 rows
+    monkeypatch.setattr(dense_lm, "block_sizes",
+                        lambda m, n, S, limit: (3, n * S))
     lm = _lm(arch, m)
     params = make_weights(3, m)
     batch = {k: jnp.asarray(v) for k, v in _batch(1).items()}
@@ -68,7 +71,7 @@ def test_forward_loss_and_grad_match_the_program(arch, m):
             lambda p: lm.loss(p, batch, remat_mask=(True,) * n_units),
             has_aux=True)(params)
         rloss, rg = dense_lm.loss_and_grad(make_weights(3, m, program=False),
-                                           _batch(1), m, rows=3)
+                                           _batch(1), m)
     assert abs(float(loss) - rloss) <= 1e-5 * abs(rloss)
     gap, where = check.worst_leaf_gap(
         check.leaf_norms(from_program(g, m)), check.leaf_norms(rg))
@@ -101,7 +104,8 @@ def test_trainer_step_under_a_remat_plan_matches_the_reference():
         prog["change"] = check.diff_norms(from_program(params, m),
                                           make_weights(5, m, program=False))
         from bench.lib.train_cell import reference_readings
-        ref = reference_readings({"model": m, "train": {"adamw": ADAMW}},
+        ref = reference_readings(dense_lm,
+                                 {"model": m, "train": {"adamw": ADAMW}},
                                  5, batches)
     nums = check.train_numbers(prog, ref)
     assert nums["loss_gap"] <= 1e-5
@@ -127,7 +131,7 @@ def test_serve_engine_prefill_and_decode_match_the_reference():
     from bench.lib.serve_cell import reference_gaps
     samples = [(lv.req.prompt, lv.tokens) for lv in eng.done]
     assert len(samples) == 3
-    gaps = reference_gaps({"model": m}, 9, samples)
+    gaps = reference_gaps(dense_lm, {"model": m}, 9, samples)
     assert max(float(g.max()) for g in gaps) <= 1e-4
 
 
