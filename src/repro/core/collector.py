@@ -247,6 +247,7 @@ class ShuttlingCollector:
         self.mesh_budget = mesh_budget
         self._mesh_sig = mesh_budget.sig() if mesh_budget is not None else None
         self._trace_cache: Dict[tuple, dict] = {}
+        self._head_cache: Dict[tuple, int] = {}
         self.stats = {"traces": 0, "dedup_hits": 0, "collections": 0}
 
     def collect(self, params, batch) -> CollectionResult:
@@ -306,6 +307,19 @@ class ShuttlingCollector:
         return CollectionResult(input_size_of(batch), records,
                                 time.perf_counter() - t0,
                                 traced_units=traced, dedup_hits=hits)
+
+    def head_bytes(self, batch) -> int:
+        """Bytes of the blocked output head and loss at this batch's
+        geometry: every leaf of ``LM.head_parts`` (one head
+        block's forward and gradient under ``jax.eval_shape``, plus the
+        final hidden state).  Under a mesh budget it is counted whole on
+        each device, an upper bound.  Not a plan unit: it is never
+        rematted, and the planner adds it to every peak it predicts.
+        Cached by geometry."""
+        key = tuple(int(s) for s in batch["tokens"].shape)
+        if key not in self._head_cache:
+            self._head_cache[key] = _tree_bytes(self.lm.head_parts(batch))
+        return self._head_cache[key]
 
     # ------------------------------------------------------------------
     def _residual_stream_struct(self, params, batch):

@@ -98,6 +98,9 @@ class PlanInfo:
     estimate_time_s: float = 0.0
     schedule_time_s: float = 0.0
     collect_time_s: float = 0.0
+    # bytes of the blocked output head and loss at this bucket, which
+    # the plan's peak holds on top of the fixed bytes (0: not counted)
+    head_bytes: float = 0.0
 
 
 class PlannerBase:
@@ -549,8 +552,16 @@ class MimosePlanner(PlannerBase):
                            for k, v in probe.items()
                            if np.shape(v)}})
 
+    def planned_fixed_bytes(self, params, batch) -> float:
+        """What every plan of this batch's bucket holds whatever it
+        remats: parameters, gradients and optimizer state, and the
+        blocked output head and loss at the bucket's geometry
+        (``ShuttlingCollector.head_bytes``)."""
+        return (self.resolve_fixed_bytes(params)
+                + self.collector.head_bytes(batch))
+
     def _record_drift_point(self, bucket: int, size: int, est, truth,
-                            rel_err: float = 0.0,
+                            fixed: float, rel_err: float = 0.0,
                             refit: bool = False) -> None:
         """One point of the predicted-vs-actual peak-bytes series: the
         drift-audit (and every sheltered collection) compares the
@@ -559,8 +570,6 @@ class MimosePlanner(PlannerBase):
         per-bucket gauges and a ``drift`` event instead of discarding
         it after the refit decision."""
         div = self.activation_divisor_scalar()
-        fixed = float(self.fixed_bytes) if self.fixed_bytes is not None \
-            else 0.0
         pred = fixed + float(np.sum(est)) / div
         act = fixed + float(np.sum(truth)) / div
         m = self.telemetry.metrics
@@ -626,6 +635,8 @@ class MimosePlanner(PlannerBase):
         # the ONE cache-key construction (PlannerBase.plan_key): growing
         # a key component there covers every planner at once
         key = self.plan_key(batch)
+        head = self.collector.head_bytes(batch)
+        fixed = self.resolve_fixed_bytes(params) + head
         with self._cache_lock:
             p = self.cache.get(key)
         if p is not None:
@@ -633,7 +644,8 @@ class MimosePlanner(PlannerBase):
             # a background-solved plan lands here on the next step of
             # its bucket — no blocking, the daemon already swapped it in
             self._maybe_submit_solve(params, batch, key, p)
-            return p.as_actions(), PlanInfo(s, qs, True, False, p)
+            return p.as_actions(), PlanInfo(s, qs, True, False, p,
+                                            head_bytes=head)
         self.stats["cache_misses"] += 1
 
         tel = self.telemetry
@@ -656,7 +668,7 @@ class MimosePlanner(PlannerBase):
             t_col = res.collect_time_s
             self.stats["collections"] += 1
             self.stats["collect_time_s"] += t_col
-            self._record_drift_point(qs, s, est, est)
+            self._record_drift_point(qs, s, est, est, fixed)
         else:
             t0 = time.perf_counter()
             with tel.tracer.span("predict", TRACK_PLANNER):
@@ -673,7 +685,7 @@ class MimosePlanner(PlannerBase):
                 err = abs(truth.sum() - est.sum()) / max(truth.sum(), 1.0)
                 refit = err > self.audit_tol
                 audited = True
-                self._record_drift_point(qs, s, est, truth,
+                self._record_drift_point(qs, s, est, truth, fixed,
                                          rel_err=err, refit=refit)
                 if refit:
                     self._feed_estimators(s, audit_res, batch)
@@ -707,7 +719,7 @@ class MimosePlanner(PlannerBase):
                 div = self.activation_divisor_scalar()
                 plan = greedy_plan(est / div,
                                    self.budget_bytes,
-                                   self.resolve_fixed_bytes(params),
+                                   fixed,
                                    tol=self.bucket_tol,
                                    flops=self.planning_flops(flops),
                                    **self._hybrid_kwargs(s, res))
@@ -716,7 +728,7 @@ class MimosePlanner(PlannerBase):
                     lambda k: self._microbatch_vectors(params, batch, k,
                                                        est, flops, res),
                     self.budget_bytes,
-                    self.resolve_fixed_bytes(params),
+                    fixed,
                     candidate_ks=ks,
                     tol=self.bucket_tol,
                     pcie_bytes_per_s=self.pcie_gbps * 1e9,
@@ -732,8 +744,7 @@ class MimosePlanner(PlannerBase):
             div = self.activation_divisor_scalar()
             self.telemetry.metrics.gauge(
                 "plan_predicted_peak_bytes").set(
-                    float(self.fixed_bytes or 0.0)
-                    + float(np.sum(est)) / div, bucket=qs)
+                    fixed + float(np.sum(est)) / div, bucket=qs)
 
         ev_before = self.cache.evictions
         with self._cache_lock:
@@ -755,7 +766,8 @@ class MimosePlanner(PlannerBase):
                                 evictions=int(self.cache.evictions))
         self._maybe_submit_solve(params, batch, key, plan)
         return plan.as_actions(), PlanInfo(s, qs, False, collected, plan,
-                                           t_est, t_sch, t_col)
+                                           t_est, t_sch, t_col,
+                                           head_bytes=head)
 
     # ------------------------------------------------------------------
     def _maybe_submit_solve(self, params, batch, key, plan) -> None:
@@ -785,7 +797,8 @@ class MimosePlanner(PlannerBase):
         req = SolveRequest(key=key, bucket=self.bucket_key(batch),
                            vectors=vectors,
                            budget_bytes=self.budget_bytes,
-                           fixed_bytes=self.resolve_fixed_bytes(params),
+                           fixed_bytes=self.planned_fixed_bytes(params,
+                                                                batch),
                            candidate_ks=tuple(ks),
                            pcie_bytes_per_s=self.pcie_gbps * 1e9,
                            offload_overlap=self.offload_overlap,
@@ -839,7 +852,7 @@ class MimosePlanner(PlannerBase):
         div = self.activation_divisor_scalar()
         flops = (res.flops_vector() if res is not None
                  else plan_unit_flops(self.lm, batch))
-        fixed = self.resolve_fixed_bytes(params)
+        fixed = self.planned_fixed_bytes(params, batch)
         budget = self.budget_bytes * (self.escalate_shrink ** level)
         with self._cache_lock:
             prev = self.cache.get(key)

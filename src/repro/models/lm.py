@@ -298,6 +298,119 @@ class PlanUnit:
 
 
 # ---------------------------------------------------------------------------
+# blocked output head and cross-entropy
+# ---------------------------------------------------------------------------
+
+# a block holds at least this many tokens: a block reads the head twice
+# and reads and writes its fp32 gradient once, 12-16 bytes per entry
+# against 6 FLOPs per entry and token; at 1024 tokens one v5e chip's
+# matrix unit takes about twice as long as that traffic, which then
+# hides under it (at 512 tokens a v5e ran the fp32 gradient sum at half
+# its peak)
+HEAD_MIN_TOKENS = 1024
+# beyond that, as many tokens as keep one block's fp32 logits this small
+HEAD_BLOCK_BYTES = 1 << 28
+
+
+def head_block_tokens(n_tokens: int, vocab: int) -> int:
+    """Tokens in one block of the blocked output head and loss.
+
+    The rule: a block may hold as many tokens as keep its fp32 logits
+    within ``HEAD_BLOCK_BYTES`` (256 MiB), rounded down to a multiple of
+    128 (the matrix unit's width), but at least ``HEAD_MIN_TOKENS``
+    (1024), below which it waits on the head's weight traffic.  The
+    step's tokens are cut into the fewest such blocks, of equal size
+    rounded up to 8, so little of the last block is padding.
+    Vocabulary 151936 takes blocks of 1024 tokens; 30522 at most 2176
+    (2048 for 48 x 512 tokens)."""
+    n = int(n_tokens)
+    most = max(HEAD_MIN_TOKENS,
+               HEAD_BLOCK_BYTES // (4 * int(vocab)) // 128 * 128)
+    per_block = -(-n // -(-n // most))
+    return -(-per_block // 8) * 8
+
+
+def _xent_block(h, w, labels, scale, vocab_rows: bool) -> dict:
+    """One block of tokens through the output head: the scaled negative
+    log-likelihood sum and its gradients, with the block's logits and
+    their cotangent.  ``h`` (T, d); ``w`` (V, d) when ``vocab_rows``,
+    else (d, V); ``scale`` (T,) each token's weight over the total.
+
+    dlogits = (softmax - onehot) * scale, cast to the operands' dtype as
+    autodiff of ``h @ w`` would; both gradient products accumulate in
+    fp32.  The label logit is a one-hot contraction inside the block, so
+    a model-sharded vocabulary reduces instead of all-gathering."""
+    f32 = jnp.float32
+    dims = (((1,), (1,)), ((), ())) if vocab_rows else (((1,), (0,)), ((), ()))
+    logits = jax.lax.dot_general(h, w, dims, preferred_element_type=f32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    onehot = jax.nn.one_hot(labels, logits.shape[-1], dtype=f32)
+    nll = lse - jnp.sum(logits * onehot, axis=-1)
+    dlogits = ((jnp.exp(logits - lse[:, None]) - onehot)
+               * scale[:, None]).astype(jnp.result_type(h, w))
+    tokens = (((0,), (0,)), ((), ()))
+    if vocab_rows:
+        dw = jax.lax.dot_general(dlogits, h, tokens,
+                                 preferred_element_type=f32)
+        dh = jnp.dot(dlogits, w, preferred_element_type=f32)
+    else:
+        dw = jax.lax.dot_general(h, dlogits, tokens,
+                                 preferred_element_type=f32)
+        dh = jax.lax.dot_general(dlogits, w, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=f32)
+    return {"loss": jnp.sum(nll * scale), "dh": dh.astype(h.dtype),
+            "dw": dw, "logits": logits, "dlogits": dlogits}
+
+
+def _xent_grads(h, w, labels, scale, block: int, vocab_rows: bool):
+    """``blocked_xent``'s value and its gradients in ``h`` and ``w``,
+    one ``lax.scan`` step a block; tokens past the last whole block are
+    padded with weight 0."""
+    n, d = h.shape
+    nb = -(-n // block)
+    pad = nb * block - n
+    if pad:
+        h = jnp.pad(h, ((0, pad), (0, 0)))
+        labels = jnp.pad(labels, (0, pad))
+        scale = jnp.pad(scale, (0, pad))
+
+    def body(carry, xs):
+        (loss, dw), (hb, lb, sb) = carry, xs
+        out = _xent_block(hb, w, lb, sb, vocab_rows)
+        return (loss + out["loss"], dw + out["dw"]), out["dh"]
+
+    init = (jnp.zeros((), jnp.float32), jnp.zeros(w.shape, jnp.float32))
+    (loss, dw), dh = jax.lax.scan(
+        body, init, (h.reshape(nb, block, d), labels.reshape(nb, block),
+                     scale.reshape(nb, block)))
+    return loss, dh.reshape(nb * block, d)[:n], dw.astype(w.dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def blocked_xent(h, w, labels, scale, block: int, vocab_rows: bool):
+    """sum_t scale_t * (logsumexp(h_t w) - (h_t w)[labels_t]) over blocks
+    of ``block`` tokens.  The forward pass computes the gradients in
+    ``h`` and ``w`` block by block, so the head costs the forward and
+    backward FLOPs of ``h @ w`` with no recompute, and holds one block's
+    logits; the backward only scales them by the incoming cotangent."""
+    return _xent_grads(h, w, labels, scale, block, vocab_rows)[0]
+
+
+def _blocked_xent_fwd(h, w, labels, scale, block, vocab_rows):
+    loss, dh, dw = _xent_grads(h, w, labels, scale, block, vocab_rows)
+    return loss, (dh, dw)
+
+
+def _blocked_xent_bwd(block, vocab_rows, res, g):
+    dh, dw = res
+    return ((g * dh).astype(dh.dtype), (g * dw).astype(dw.dtype), None,
+            None)
+
+
+blocked_xent.defvjp(_blocked_xent_fwd, _blocked_xent_bwd)
+
+
+# ---------------------------------------------------------------------------
 # the LM shell
 # ---------------------------------------------------------------------------
 
@@ -311,7 +424,8 @@ class LM:
         # Megatron-style sequence-parallel residual stream — shard the
         # seq axis of the inter-block activations over the model axis.
         self.act_sharding = None          # NamedSharding or None
-        # keep logits in bf16 (CE reductions still accumulate in f32)
+        # forward()'s logits in f32 (False: the model's dtype); the
+        # training loss computes each block's logits in f32 either way
         self.logits_f32 = True
         # prefill: emit logits for the last position only (serving needs
         # nothing else; full-sequence logits dominate prefill memory)
@@ -437,6 +551,28 @@ class LM:
         kernels mask — and, where blockwise, skip — the padded tail.
         """
         cfg = self.cfg
+        x, aux = self._trunk(params, batch, remat_mask, remat_policy)
+        if self.last_logits_only:
+            x = x[:, -1:]
+        with jax.named_scope("head"):
+            x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
+            w, vocab_rows = self._head_weight(params)
+            logits = x @ (w.T if vocab_rows else w)
+            if self.logits_f32:
+                logits = logits.astype(jnp.float32)
+        return logits, aux
+
+    def _head_weight(self, params) -> Tuple[Array, bool]:
+        """The output head as stored, and whether its rows are the
+        vocabulary: the tied embedding (V, d), or ``lm_head`` (d, V)."""
+        if self.cfg.tie_embeddings:
+            return params["embed"], True
+        return params["lm_head"], False
+
+    def _trunk(self, params, batch, remat_mask=None, remat_policy=None):
+        """Embedding and every plan unit: the last hidden state (before
+        the final norm) and the summed auxiliary loss."""
+        cfg = self.cfg
         x, positions, mrope_positions = self._embed_inputs(params, batch)
         aux = jnp.zeros((), jnp.float32)
         seq_lens = batch.get("lengths")
@@ -482,17 +618,7 @@ class LM:
                     x, a = one(bp, x)
                 x = self._constrain(x)
                 aux = aux + a
-
-        if self.last_logits_only:
-            x = x[:, -1:]
-        with jax.named_scope("head"):
-            x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-            head = (params["embed"].T if cfg.tie_embeddings
-                    else params["lm_head"])
-            logits = x @ head
-            if self.logits_f32:
-                logits = logits.astype(jnp.float32)
-        return logits, aux
+        return x, aux
 
     def _forward_scan(self, params, x, positions, chunk_actions, enc_out,
                       mrope_positions, remat_policy, seq_lens=None):
@@ -603,29 +729,61 @@ class LM:
 
     # -- loss ---------------------------------------------------------------
     def loss(self, params, batch, remat_mask=None, remat_policy=None):
+        """Token-weighted mean cross-entropy (plus the family's auxiliary
+        loss).  The final norm, output head and cross-entropy run over
+        blocks of tokens (``blocked_xent``), whose gradient is taken in
+        the forward pass: no (B, S, V) logits exist."""
         cfg = self.cfg
-        logits, aux = self.forward(params, batch, remat_mask, remat_policy)
+        x, aux = self._trunk(params, batch, remat_mask, remat_policy)
         labels = batch["labels"]
         if cfg.family == "vlm" and cfg.vision_tokens:
-            logits = logits[:, cfg.vision_tokens:]           # text positions only
+            x = x[:, cfg.vision_tokens:]                     # text positions only
         weights = batch.get("weights")
         if weights is None:
             weights = jnp.ones(labels.shape, jnp.float32)
-        # sharding-friendly cross entropy: the vocab axis of ``logits`` is
-        # model-sharded, so avoid take_along_axis (which would all-gather
-        # the full logits).  one_hot contracts the vocab axis locally and
-        # reduces across the model axis instead.
-        with jax.named_scope("head"):
-            lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
-            onehot = jax.nn.one_hot(labels, logits.shape[-1],
-                                    dtype=logits.dtype)
-            label_logit = jnp.einsum("bsv,bsv->bs", logits, onehot,
-                                     preferred_element_type=jnp.float32)
-            nll = lse - label_logit
         total_w = jnp.maximum(jnp.sum(weights), 1.0)
-        ce = jnp.sum(nll * weights) / total_w
+        B, S, d = x.shape
+        w, vocab_rows = self._head_weight(params)
+        with jax.named_scope("head"):
+            h = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
+            ce = blocked_xent(h.reshape(B * S, d), w, labels.reshape(-1),
+                              (weights / total_w).astype(jnp.float32)
+                              .reshape(-1),
+                              head_block_tokens(B * S, cfg.vocab_size),
+                              vocab_rows)
         loss = ce + aux
         return loss, {"ce": ce, "aux": aux, "tokens": total_w}
+
+    def head_blocks(self, batch) -> int:
+        """Blocks of tokens the output head and loss run in."""
+        n = math.prod(batch["tokens"].shape)
+        return -(-n // head_block_tokens(n, self.cfg.vocab_size))
+
+    def head_parts(self, batch) -> dict:
+        """What the blocked head and loss hold at once, as shapes: the
+        final hidden state (the final norm's input, kept for its
+        backward) and its normed copy, the hidden state's whole
+        gradient, the fp32 gradient of the head being summed, and one
+        block's forward and gradient (``_xent_block`` under
+        ``jax.eval_shape``).  Works on arrays and ``ShapeDtypeStruct``
+        batches alike."""
+        cfg = self.cfg
+        B, St = batch["tokens"].shape
+        n, d, V = B * St, cfg.d_model, cfg.vocab_size
+        block = head_block_tokens(n, V)
+        vocab_rows = cfg.tie_embeddings
+        hidden = jax.ShapeDtypeStruct((n, d), self.dtype)
+        w = jax.ShapeDtypeStruct((V, d) if vocab_rows else (d, V),
+                                 self.dtype)
+        one = jax.eval_shape(
+            lambda hb, ww, lb, sb: _xent_block(hb, ww, lb, sb, vocab_rows),
+            jax.ShapeDtypeStruct((block, d), self.dtype), w,
+            jax.ShapeDtypeStruct((block,), jnp.int32),
+            jax.ShapeDtypeStruct((block,), jnp.float32))
+        padded = self.head_blocks(batch) * block
+        return {"final_hidden": hidden, "normed": hidden,
+                "hidden_grad": jax.ShapeDtypeStruct((padded, d), self.dtype),
+                "head_grad_sum": one["dw"], "block": one}
 
     # -- decode -------------------------------------------------------------
     def init_cache(self, batch_size: int, max_len: int) -> Any:

@@ -107,8 +107,13 @@ class StepStats:
     exposed_transfer_s: float = 0.0
     sim_transfer_s: float = 0.0
     # the planner's predicted per-device peak under this step's plan:
-    # fixed bytes plus the activation bytes the plan keeps on the device
+    # fixed bytes, the output head's, and the activation bytes the plan
+    # keeps on the device
     planned_peak_bytes: float = 0.0
+    # the planner's term for the blocked output head and loss at this
+    # step's bucket, and the head's number of blocks of tokens
+    head_bytes: float = 0.0
+    head_blocks: int = 0
 
 
 class _ReadsPending:
@@ -201,6 +206,12 @@ class Trainer:
         self._g_bucket_k = reg.gauge(
             "train_bucket_microbatch",
             "largest gradient-accumulation split seen per bucket")
+        self._g_head_bytes = reg.gauge(
+            "train_head_bytes",
+            "the planner's bytes for the blocked output head per bucket")
+        self._g_head_blocks = reg.gauge(
+            "train_head_blocks",
+            "blocks of tokens the output head and loss run in per bucket")
         self._h_step_s = reg.histogram(
             "train_step_time_s", "wall time per executed train step")
         self._m_deferred = reg.counter(
@@ -716,7 +727,11 @@ class Trainer:
             st = getattr(self.planner, "stats", None)
             if isinstance(st, MutableMapping):
                 st["offload_fallbacks"] = st.get("offload_fallbacks", 0) + 1
-        fixed = float(self.planner.fixed_bytes or 0.0)
+        head = float(info.head_bytes)
+        head_blocks = self.lm.head_blocks(batch)
+        self._g_head_bytes.set(head, bucket=bucket)
+        self._g_head_blocks.set(head_blocks, bucket=bucket)
+        fixed = float(self.planner.fixed_bytes or 0.0) + head
         planned_peak = fixed + (
             float(info.plan.est_activation_bytes)
             - float(info.plan.covered_bytes)
@@ -730,7 +745,8 @@ class Trainer:
                           offload_degraded=degraded,
                           exposed_transfer_s=exposed_s,
                           sim_transfer_s=sim_s,
-                          planned_peak_bytes=planned_peak)
+                          planned_peak_bytes=planned_peak,
+                          head_bytes=head, head_blocks=head_blocks)
         stats._settle = self.drain
         self.history.append(stats)
         event = None

@@ -187,7 +187,9 @@ def test_greedy_plan_respects_per_device_budget(toy):
         mask, info = planner.plan(params, batch)
         col = planner.collector.collect(params, batch)
         act = col.device_activation_vector()
-        fixed = planner.resolve_fixed_bytes(params)
+        # what the planner holds whatever it remats: the fixed bytes and
+        # the output head's at this geometry
+        fixed = planner.planned_fixed_bytes(params, batch)
         # mask is a typed action tuple now: KEEP units are the saved ones
         saved = float(act[np.asarray(mask, dtype=int) == 0].sum())
         assert fixed + saved <= budget.hbm_per_device_bytes, shape
